@@ -1,7 +1,7 @@
 # One function per paper table/figure. Prints ``name,us_per_call,derived``
 # CSV rows; --full widens the CV folds and range sweeps to paper scale;
-# --smoke shrinks every sweep to a seconds-scale pass AND makes any
-# benchmark error fatal (exit 1) — the CI bit-rot guard for entrypoints.
+# --smoke shrinks every sweep to a seconds-scale pass and adds the resource
+# certifier.  A benchmark that raises fails the run (exit 1) in every mode.
 from __future__ import annotations
 
 import argparse
@@ -129,7 +129,8 @@ def main() -> int:
     ap.add_argument("--full", action="store_true",
                     help="paper-scale settings (10-fold CV, all ranges)")
     ap.add_argument("--smoke", action="store_true",
-                    help="minimal settings, errors are fatal (CI mode)")
+                    help="minimal settings plus the resource certifier "
+                         "(CI mode)")
     ap.add_argument("--compression-json",
                     help="also write the compression rows gathered during "
                          "this run to a JSON artifact (avoids re-running "
@@ -160,6 +161,8 @@ def main() -> int:
     args = ap.parse_args()
     if args.full and args.smoke:
         ap.error("--full and --smoke are mutually exclusive")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (compression_bench, engine_bench, event_bench,
                             fault_bench, fig7_retained_variance,
@@ -253,12 +256,10 @@ def main() -> int:
         except Exception as e:  # noqa: BLE001 — certifier crash is a finding
             resource_errors = [("resources:driver",
                                 f"{type(e).__name__}: {e}")]
-    # bench/artifact/resource errors are fatal only under --smoke (CI
-    # mode); a throughput regression is fatal on every run
-    fatal = regressions + (bench_errors + artifact_errors + resource_errors
-                           if args.smoke else [])
-    warn_only = [] if args.smoke else bench_errors + artifact_errors
-    for rule, detail in fatal + warn_only:
+    # every failure is fatal: a benchmark that raised, a missing artifact,
+    # a regression, a certifier drift (--smoke)
+    fatal = regressions + bench_errors + artifact_errors + resource_errors
+    for rule, detail in fatal:
         print(f"run.py/FAIL,{rule},{detail}", file=sys.stdout)
     if fatal:
         print("run.py verdict: FAILED — "

@@ -25,7 +25,7 @@ import math
 from typing import Iterable, Iterator, Mapping
 
 import numpy as np
-from jax.core import ClosedJaxpr, Jaxpr, Literal
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 from repro.launch.hlo_analysis import fallback_trip
 
@@ -62,7 +62,7 @@ HOST_SYNC_PRIMITIVES = frozenset({
 class EqnSite:
     """One equation as seen by the walker."""
 
-    eqn: object                  # jax.core.JaxprEqn
+    eqn: object                  # jax.extend.core.JaxprEqn
     mult: float                  # static execution multiplier (loop trips);
     #                              NaN when an enclosing while trip is unknown
     loop_depth: int              # > 0 inside a scan/while body

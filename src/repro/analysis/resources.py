@@ -75,7 +75,7 @@ _MAX_EXACT_GRID = 65536
 class OperandTraffic:
     """One BlockSpec'd operand of one ``pallas_call``."""
 
-    origin: str                  # e.g. "args[0]" / "outputs[1]"
+    origin: str                  # the kernel ref it feeds, e.g. "x_ref"
     dtype: str
     block_shape: tuple           # ints; vmapped dims count as 1
     block_bytes: int             # one block residing in VMEM
@@ -143,14 +143,15 @@ class KernelResources:
         return self.flops / max(self.hbm_read_bytes + self.hbm_write_bytes, 1)
 
 
-def _block_elems(block_shape) -> int:
-    """Elements in one block; non-int entries (the vmap `Mapped` sentinel)
-    occupy a single slice and count as 1."""
-    n = 1
+def _block_dims(block_shape) -> tuple[int, ...]:
+    """Block extents as ints: a ``pl.Blocked`` entry gives its
+    ``block_size``; a squeezed (vmapped) dim occupies a single slice and
+    counts as 1."""
+    dims = []
     for d in block_shape:
-        if isinstance(d, (int, np.integer)):
-            n *= int(d)
-    return n
+        size = getattr(d, "block_size", d)
+        dims.append(int(size) if isinstance(size, (int, np.integer)) else 1)
+    return tuple(dims)
 
 
 def _grid_steps(grid: tuple) -> Iterator[tuple]:
@@ -170,7 +171,7 @@ def _index_map_fetches(bm, grid: tuple) -> tuple[int, bool]:
     is too large to enumerate or the index map is not a plain
     grid-indices function.
     """
-    from jax.core import eval_jaxpr
+    from jax.extend.core import jaxpr_as_fun
 
     cells = int(np.prod([int(g) for g in grid])) if grid else 1
     cj = getattr(bm, "index_map_jaxpr", None)
@@ -178,9 +179,10 @@ def _index_map_fetches(bm, grid: tuple) -> tuple[int, bool]:
         return cells, False
     if len(cj.jaxpr.invars) != len(grid):
         return cells, False            # scalar-prefetch args etc.
+    index_map = jaxpr_as_fun(cj)
     fetches, prev = 0, None
     for step in _grid_steps(grid):
-        out = eval_jaxpr(cj.jaxpr, cj.consts, *step)
+        out = index_map(*step)
         idx = tuple(int(v) for v in out)
         if idx != prev:
             fetches += 1
@@ -231,8 +233,10 @@ def _jaxpr_flops(target) -> int:
 
 
 def _kernel_name(eqn) -> str:
-    info = eqn.params.get("name_and_src_info")
-    return getattr(info, "name", None) or str(eqn.params.get("name", "?"))
+    if eqn.params.get("name"):
+        return str(eqn.params["name"])
+    src = getattr(eqn.params["jaxpr"].debug_info, "func_src_info", None)
+    return src.split(" ")[0] if src else "?"
 
 
 def pallas_resources(target) -> list[KernelResources]:
@@ -245,16 +249,23 @@ def pallas_resources(target) -> list[KernelResources]:
         gm = site.eqn.params["grid_mapping"]
         grid = tuple(int(g) for g in gm.grid)
         n_in = int(gm.num_inputs)
+        # operands are named by the kernel's ref parameters (``x_ref``...),
+        # which the block mappings follow in order (inputs, then outputs)
+        arg_names = getattr(site.eqn.params["jaxpr"].debug_info,
+                            "arg_names", None) or ()
         rows: list[OperandTraffic] = []
-        for bm in gm.block_mappings:
-            sdt = bm.array_shape_dtype
+        for i, bm in enumerate(gm.block_mappings):
+            sdt = bm.array_aval
             itemsize = int(np.dtype(sdt.dtype).itemsize)
-            block_bytes = _block_elems(bm.block_shape) * itemsize
+            dims = _block_dims(bm.block_shape)
+            block_bytes = int(np.prod(dims)) * itemsize
             array_bytes = int(np.prod(sdt.shape)) * itemsize
             fetches, exact = _index_map_fetches(bm, grid)
             rows.append(OperandTraffic(
-                origin=str(bm.origin), dtype=str(np.dtype(sdt.dtype)),
-                block_shape=tuple(bm.block_shape),
+                origin=(arg_names[i] if i < len(arg_names)
+                        else str(bm.origin)),
+                dtype=str(np.dtype(sdt.dtype)),
+                block_shape=dims,
                 block_bytes=block_bytes, array_bytes=array_bytes,
                 fetches=fetches, fetched_bytes=fetches * block_bytes,
                 exact=exact))
@@ -512,7 +523,7 @@ class HbmTrafficBudget:
     fails loudly."""
 
     max_passes: float
-    single_pass: tuple[str, ...] = ()   # operand origins, e.g. ("args[0]",)
+    single_pass: tuple[str, ...] = ()   # operand origins, e.g. ("x_ref",)
 
     @property
     def name(self) -> str:
@@ -539,13 +550,17 @@ class HbmTrafficBudget:
                 f"(budget <= {self.max_passes:.2f} — an extra kernel "
                 f"round trip?)")
         for origin in self.single_pass:
+            found = False
             for k in entry.kernels:
                 for o in k.inputs:
+                    found |= o.origin == origin
                     if o.origin == origin and o.fetched_bytes > o.array_bytes:
                         problems.append(
                             f"{k.name} operand {origin} fetched "
                             f"{o.passes:.2f}x (must be one tile-load: "
                             f"{_fmt_bytes(o.array_bytes)})")
+            if not found:
+                problems.append(f"no kernel operand named {origin}")
         if problems:
             return RuleReport(self.name, False, "; ".join(problems))
         return RuleReport(

@@ -21,39 +21,34 @@ into VMEM once and produces, from the same tiles,
 
 collapsing the chunk body from 3 kernel launches to 1.
 
-Tiling: the grid is (feature blocks, row blocks) — EXACTLY the cov chunk
-kernel's grid, with the same block specs and the same fold body, so the
-band accumulator is produced by the same sequence of loads, multiplies and
-row reductions and its fp32 bits are identical to the split kernel's (the
-differential guarantee of tests/test_fused_stream.py; XLA re-vectorizes a
-reduction when the tile shapes around it change, so structural congruence
-is what carries bit-equality, not just the math).  The band output block
-has a j-constant index map and is revisited consecutively across the row
-sweep of each feature block — the Pallas in-VMEM accumulation pattern.
+Tiling: the grid is (feature blocks, row blocks) — the cov chunk
+kernel's grid, with the same block specs and the same fold
+(:func:`repro.kernels.cov_update.band_fold`: one lane-aligned halo window
+per step, shifted views by lane rotation).  The band output block has a
+j-constant index map and is revisited consecutively across the row sweep
+of each feature block — the Pallas in-VMEM accumulation pattern.
 
 The stages run once per row block, on the FIRST feature step
-(``pl.program_id(0) == 0``), reading the full-width rows back out of the
-halo slab (which is resident anyway for the shifted band products) at the
-exact unpadded sensor count — a feature-padded chunk (awkward p) must not
-change the stage dots' reduction width, or their bits would drift from the
-standalone stage kernels.  The stage outputs advance with the row block
-and are written only on that first feature step; with more than one
-feature block those output blocks are technically revisited (idly) later
-in the sweep, which interpret mode carries through untouched — on a real
-TPU backend the roofline feature targets (:mod:`repro.launch.tiling`) keep
-p inside one feature block for every WSN-scale network, so the idle
-revisit never materializes there.
+(``pl.program_id(0) == 0``), reading the full rows back out of the halo
+slab (which is resident anyway for the shifted band products) at the
+lane-aligned offset ``halo_pad(h)`` and the padded width ``p_pad``: the
+basis and mean are zero-padded to ``p_pad`` and pad columns carry mask 0,
+so they add exact zeros, and the wrapper slices the outputs back to ``p``.
+The stage outputs' index map advances with the row block on the first
+feature step and then stays on the last row block for the rest of the
+sweep, so a block the kernel did not write is never written back (with
+more than one feature block a plain ``(j, 0)`` map would write stale VMEM
+buffers over the stage outputs on the chip).
 
 Precision: every tile is cast to fp32 on load and every accumulation runs
 in fp32 (``preferred_element_type=jnp.float32``), whatever the operand
 dtype — so the optional bf16 mode (the ops wrapper casts the large
 operands x/xpad/mask/W to bfloat16 before the call) halves the HBM tile
 traffic while the band fold and the stage reductions keep fp32
-accumulators.  With fp32 operands the arithmetic (and hence, in interpret
-mode, the bits) is identical to the three split kernels: the band part
-replicates ``_chunk_masked_kernel`` load-for-load and the stage part
-replicates ``_supervised_kernel``/``_monitor_kernel`` op-for-op per
-(block_n, p) slab.
+accumulators.  With fp32 operands the band is the chunk kernel's fold and
+the stages are ``_supervised_kernel``/``_monitor_kernel``'s op order per
+row block; the compiled programs differ, so the split path agrees to fp32
+rounding, not bit for bit.
 """
 
 from __future__ import annotations
@@ -64,18 +59,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.cov_update import (band_fold, band_rows_pad, halo_pad,
+                                      load_window)
+
 __all__ = ["fused_stream_pallas"]
 
 
-def _fused_kernel(x_ref, xpad_ref, m_ref, mpad_ref, w_ref, basis_ref,
-                  mean_ref, invlam_ref, *out_refs,
-                  nb: int, p: int, eps: float, with_compress: bool,
-                  with_monitor: bool):
+def _fused_kernel(xpad_ref, mpad_ref, w_ref, basis_ref, mean_ref,
+                  invlam_ref, *out_refs, halfwidth: int, block_p: int,
+                  eps: float, with_compress: bool, with_monitor: bool):
     i = pl.program_id(0)                    # feature block (band fold)
     j = pl.program_id(1)                    # row block
-    block_p = x_ref.shape[1]
-    base = i * block_p
-    h = (nb - 1) // 2
+    pad = halo_pad(halfwidth)
+    p_pad = basis_ref.shape[0]
     band_ref = out_refs[0]
     z_ref = out_refs[1]
     k_out = 2
@@ -89,31 +85,25 @@ def _fused_kernel(x_ref, xpad_ref, m_ref, mpad_ref, w_ref, basis_ref,
     def _init():
         band_ref[...] = jnp.zeros_like(band_ref)
 
-    # --- band fold: line-for-line _chunk_masked_kernel (mask fused into
-    # the tile load, then the per-row round weight; shifted operand masked
-    # but unweighted, so each product carries its weight once)
-    xw = (x_ref[...] * m_ref[...]).astype(jnp.float32) \
-        * w_ref[...].astype(jnp.float32)
-    rows = []
-    for k in range(nb):
-        sl = pl.dslice(base + k, block_p)
-        xs = (xpad_ref[:, sl] * mpad_ref[:, sl]).astype(jnp.float32)
-        rows.append(jnp.sum(xw * xs, axis=0))           # (block_p,)
-    band_ref[...] = band_ref[...] \
-        + jnp.stack(rows, axis=0).astype(band_ref.dtype)
+    # --- band fold: the masked chunk kernel's (masked window, centre tile
+    # times the per-row round weight; shifted operand masked but
+    # unweighted, so each product carries its weight once)
+    win = load_window(xpad_ref, mpad_ref, block_p=block_p,
+                      halfwidth=halfwidth)
+    xw = win[:, pad:pad + block_p] * w_ref[...].astype(jnp.float32)
+    band_fold(xw, win, band_ref, halfwidth=halfwidth)
 
     # --- stages, once per row block on the first feature step: identical
     # op order to _supervised_kernel/_monitor_kernel (the projection and
     # the VMEM-resident reconstruction are shared — the split kernels each
     # recomputed them from their own tile loads).  Rows come back out of
-    # the halo slab at the EXACT width p, so a feature-padded chunk does
-    # not widen the stage dots.
+    # the halo slab at the lane-aligned offset ``pad``.
     @pl.when(i == 0)
     def _stages():
-        x = xpad_ref[:, pl.dslice(h, p)].astype(jnp.float32)
-        m = mpad_ref[:, pl.dslice(h, p)].astype(jnp.float32)
-        w = basis_ref[...].astype(jnp.float32)          # (p, q)
-        mean = mean_ref[...].astype(jnp.float32)        # (1, p)
+        x = xpad_ref[:, pad:pad + p_pad].astype(jnp.float32)
+        m = mpad_ref[:, pad:pad + p_pad].astype(jnp.float32)
+        w = basis_ref[...].astype(jnp.float32)          # (p_pad, q)
+        mean = mean_ref[...].astype(jnp.float32)        # (1, p_pad)
         xc = (x - mean) * m
         z = jnp.dot(xc, w, preferred_element_type=jnp.float32)
         xh_r = jnp.dot(z, w.T, preferred_element_type=jnp.float32)
@@ -133,8 +123,7 @@ def _fused_kernel(x_ref, xpad_ref, m_ref, mpad_ref, w_ref, basis_ref,
                                    keepdims=True).astype(spe_ref.dtype)
 
 
-def fused_stream_pallas(x: jnp.ndarray, x_padded: jnp.ndarray,
-                        mask: jnp.ndarray, mask_padded: jnp.ndarray,
+def fused_stream_pallas(x: jnp.ndarray, mask: jnp.ndarray,
                         w_rows: jnp.ndarray, basis: jnp.ndarray,
                         mean: jnp.ndarray, inv_lam: jnp.ndarray,
                         *, halfwidth: int, epsilon: float,
@@ -144,72 +133,76 @@ def fused_stream_pallas(x: jnp.ndarray, x_padded: jnp.ndarray,
     """One fused chunk pass: band fold + compression + monitoring.
 
     ``x`` is the flattened chunk (rows, p_pad) (rows = K·n padded to
-    ``block_n``, features padded to ``block_p``); ``x_padded`` its
-    (rows, p_pad + 2h) halo form; ``mask`` / ``mask_padded`` the per-row
-    0/1 validity (liveness × round validity — pad rows carry mask 0 AND
+    ``block_n``, features padded to ``block_p``); ``mask`` the per-row 0/1
+    validity (liveness × round validity — pad rows carry mask 0 AND
     weight 0, pad features mask 0); ``w_rows`` (rows, 1) the per-row
-    forgetting weights; ``basis`` (p, q), ``mean`` (1, p) and ``inv_lam``
-    (1, q) the stage operands at the EXACT sensor count p (p <= p_pad),
+    forgetting weights; ``basis`` (p_pad, q), ``mean`` (1, p_pad) and
+    ``inv_lam`` (1, q) the stage operands, zero-padded to ``p_pad`` and
     replicated to every grid step.
 
     Returns ``(band, z[, x_hat, flags][, t2, spe])`` — band (2h+1, p_pad)
-    and per-row stage outputs at exact width, all fp32, gated by the
-    static ``with_*`` flags (at least one must be set; a band-only chunk
-    has no reason to pay the stage operand traffic — use the cov-update
-    kernel).
+    and per-row stage outputs (x_hat and flags p_pad wide), all fp32,
+    gated by the static ``with_*`` flags (at least one must be set; a
+    band-only chunk has no reason to pay the stage operand traffic — use
+    the cov-update kernel).
     """
     rows, p_pad = x.shape
     h = halfwidth
-    nb = 2 * h + 1
-    p, q = basis.shape
-    assert p <= p_pad, (p, p_pad)
+    pad = halo_pad(h)
+    q = basis.shape[1]
     assert with_compress or with_monitor, "band-only: use cov_band_update"
     assert rows % block_n == 0, (rows, block_n)
     assert p_pad % block_p == 0, (p_pad, block_p)
-    assert x_padded.shape == (rows, p_pad + 2 * h)
     assert mask.shape == (rows, p_pad)
-    assert mask_padded.shape == (rows, p_pad + 2 * h)
     assert w_rows.shape == (rows, 1)
-    assert mean.shape == (1, p) and inv_lam.shape == (1, q)
+    assert basis.shape == (p_pad, q)
+    assert mean.shape == (1, p_pad) and inv_lam.shape == (1, q)
     grid = (p_pad // block_p, rows // block_n)
+    last = rows // block_n - 1
+
+    def stage_rows(i, j):
+        # advance on the first feature step, then park on the last row
+        # block (written at i == 0, never rewritten)
+        return (jnp.where(i == 0, j, last), 0)
+
+    slab = pl.BlockSpec((block_n, p_pad + 2 * pad), lambda i, j: (j, 0))
     in_specs = [
-        pl.BlockSpec((block_n, block_p), lambda i, j: (j, i)),      # x
-        pl.BlockSpec((block_n, p_pad + 2 * h), lambda i, j: (j, 0)),
-        pl.BlockSpec((block_n, block_p), lambda i, j: (j, i)),      # mask
-        pl.BlockSpec((block_n, p_pad + 2 * h), lambda i, j: (j, 0)),
+        slab,                                                       # x
+        slab,                                                       # mask
         pl.BlockSpec((block_n, 1), lambda i, j: (j, 0)),            # weights
-        pl.BlockSpec((p, q), lambda i, j: (0, 0)),                  # basis
-        pl.BlockSpec((1, p), lambda i, j: (0, 0)),                  # mean
+        pl.BlockSpec((p_pad, q), lambda i, j: (0, 0)),              # basis
+        pl.BlockSpec((1, p_pad), lambda i, j: (0, 0)),              # mean
         pl.BlockSpec((1, q), lambda i, j: (0, 0)),                  # inv_lam
     ]
-    # the band accumulator block is revisited consecutively by the row
-    # sweep of its feature block (j-constant index map); the stage outputs
-    # advance with the row blocks and are written on the first feature step
+    n_rows = band_rows_pad(h)
     out_specs = [
-        pl.BlockSpec((nb, block_p), lambda i, j: (0, i)),           # band
-        pl.BlockSpec((block_n, q), lambda i, j: (j, 0)),            # z
+        pl.BlockSpec((n_rows, block_p), lambda i, j: (0, i)),       # band
+        pl.BlockSpec((block_n, q), stage_rows),                     # z
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((nb, p_pad), jnp.float32),
+        jax.ShapeDtypeStruct((n_rows, p_pad), jnp.float32),
         jax.ShapeDtypeStruct((rows, q), jnp.float32),
     ]
     if with_compress:
-        out_specs += [pl.BlockSpec((block_n, p), lambda i, j: (j, 0)),
-                      pl.BlockSpec((block_n, p), lambda i, j: (j, 0))]
-        out_shape += [jax.ShapeDtypeStruct((rows, p), jnp.float32),
-                      jax.ShapeDtypeStruct((rows, p), jnp.float32)]
+        out_specs += [pl.BlockSpec((block_n, p_pad), stage_rows),
+                      pl.BlockSpec((block_n, p_pad), stage_rows)]
+        out_shape += [jax.ShapeDtypeStruct((rows, p_pad), jnp.float32),
+                      jax.ShapeDtypeStruct((rows, p_pad), jnp.float32)]
     if with_monitor:
-        out_specs += [pl.BlockSpec((block_n, 1), lambda i, j: (j, 0)),
-                      pl.BlockSpec((block_n, 1), lambda i, j: (j, 0))]
+        out_specs += [pl.BlockSpec((block_n, 1), stage_rows),
+                      pl.BlockSpec((block_n, 1), stage_rows)]
         out_shape += [jax.ShapeDtypeStruct((rows, 1), jnp.float32),
                       jax.ShapeDtypeStruct((rows, 1), jnp.float32)]
-    return pl.pallas_call(
-        functools.partial(_fused_kernel, nb=nb, p=p, eps=float(epsilon),
-                          with_compress=with_compress,
+    xpad = jnp.pad(x, ((0, 0), (pad, pad)))
+    mpad = jnp.pad(mask, ((0, 0), (pad, pad)))
+    out = pl.pallas_call(
+        functools.partial(_fused_kernel, halfwidth=h, block_p=block_p,
+                          eps=float(epsilon), with_compress=with_compress,
                           with_monitor=with_monitor),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
-    )(x, x_padded, mask, mask_padded, w_rows, basis, mean, inv_lam)
+    )(xpad, mpad, w_rows, basis, mean, inv_lam)
+    return (out[0][:2 * h + 1],) + tuple(out[1:])

@@ -1,9 +1,8 @@
 """Jitted public wrappers around the Pallas kernels.
 
-Handles padding, block-size selection, dtype promotion and the
-interpret-mode fallback (this container is CPU-only: ``interpret=True``
-executes the kernel bodies in Python for correctness validation; on real TPU
-the same code path compiles to Mosaic).
+Handles padding, block-size selection and dtype promotion, and picks how
+the kernels run: compiled by Mosaic on a TPU, in the Pallas interpreter on
+the CPU (the test backend).  There is no other backend.
 """
 
 from __future__ import annotations
@@ -17,7 +16,8 @@ from repro.kernels.banded_matvec import banded_matvec_pallas, banded_matmul_pall
 from repro.kernels.cov_update import (cov_band_update_chunk_masked_pallas,
                                       cov_band_update_chunk_pallas,
                                       cov_band_update_pallas,
-                                      cov_band_update_masked_pallas)
+                                      cov_band_update_masked_pallas,
+                                      halo_pad)
 from repro.kernels.fused_stream import fused_stream_pallas
 from repro.kernels.pca_project import (pca_monitor_pallas,
                                        pca_project_pallas,
@@ -35,21 +35,17 @@ __all__ = ["banded_matvec", "banded_matmul", "cov_band_update",
 
 
 def _auto_interpret(interpret: bool | None) -> bool:
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
-
-
-@functools.lru_cache(maxsize=None)
-def _targets(kind: str, dtype: str = "fp32") -> tuple[int, int]:
-    """(row target, feature target) for a kernel family — resolved per
-    backend through :func:`repro.launch.tiling.block_targets` instead of
-    the old hard-coded (128, 512).  Non-TPU backends (this CI container)
-    get the historical numbers back, so interpret-mode bits are unchanged.
-    """
-    from repro.launch.tiling import block_targets
-    t = block_targets(kind, dtype=dtype)
-    return t["rows"], t["features"]
+    """``interpret=None`` means: compile on a TPU, interpret on the CPU.
+    Any other backend raises — a kernel never silently runs interpreted
+    where a device was expected."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise ValueError(f"no Pallas kernel path for backend {backend!r}")
 
 
 def _pick_block(p: int, target: int = 512) -> int:
@@ -60,18 +56,22 @@ def _pick_block(p: int, target: int = 512) -> int:
     return 1
 
 
-def _pick_block_padded(d: int, target: int) -> int:
+def _pick_block_padded(d: int, target: int, align: int = 1) -> int:
     """Block size for an axis the caller is allowed to zero-pad.
 
     Prefers the exact-divisor pick — no padding, so results stay
     bit-identical to the historical behavior on every shape a divisor
     covers — and only when the best divisor is degenerate (awkward ``d``,
-    e.g. prime: the old path would tile by 1, a pathological grid)
-    switches to a padded power-of-two tile.  The wrappers below pad the
+    e.g. prime: the old path would tile by 1, a pathological grid) or not
+    a multiple of ``align`` (the TPU register tile) switches to a padded
+    tile: the next power of two on the CPU, ``d`` rounded up to ``align``
+    on the TPU, both capped at ``target``.  The wrappers below pad the
     operand up to a multiple of the returned block and slice the result
     back.
     """
     b = _pick_block(d, target)
+    if align > 1:
+        return b if b % align == 0 else min(target, _pad_dim(d, align))
     if b > 1 or d <= 8:
         return b
     return min(target, 1 << (d - 1).bit_length())
@@ -97,21 +97,29 @@ def kernel_block_plan(kind: str, *, rows: int | None = None,
     plus ``grid`` (feature-major, rows fastest — the kernel convention)
     when both are, and ``halo_width`` (the full-width padded slab a banded
     kernel re-fetches per feature block) when ``halfwidth`` is given too.
+    On a TPU the row target counts that slab
+    (:func:`repro.launch.tiling.block_targets`).
     """
-    rt, ft = _targets(kind, dtype)
-    plan: dict = {"row_target": rt, "feature_target": ft}
+    from repro.launch.tiling import block_targets
+    t = block_targets(kind, dtype=dtype)
+    plan: dict = {"feature_target": t["features"]}
     if p is not None:
-        bp = _pick_block_padded(p, ft)
-        plan.update(block_p=bp, p_pad=_pad_dim(p, bp),
-                    feature_blocks=_pad_dim(p, bp) // bp)
+        bp = _pick_block_padded(p, t["features"], t["feature_align"])
+        p_pad = _pad_dim(p, bp)
+        halo = 0 if halfwidth is None else 2 * halo_pad(halfwidth)
+        plan.update(block_p=bp, p_pad=p_pad, feature_blocks=p_pad // bp)
+        if halfwidth is not None:
+            plan["halo_width"] = p_pad + halo
+        # the row target counts the blocks of one row at this width
+        t = block_targets(kind, dtype=dtype, block_p=bp, p_pad=p_pad,
+                          halo=halo)
+    plan["row_target"] = t["rows"]
     if rows is not None:
-        bn = _pick_block_padded(rows, rt)
+        bn = _pick_block_padded(rows, t["rows"], t["row_align"])
         plan.update(block_n=bn, rows_pad=_pad_dim(rows, bn),
                     row_blocks=_pad_dim(rows, bn) // bn)
     if rows is not None and p is not None:
         plan["grid"] = (plan["feature_blocks"], plan["row_blocks"])
-    if halfwidth is not None and p is not None:
-        plan["halo_width"] = plan["p_pad"] + 2 * halfwidth
     return plan
 
 
@@ -138,7 +146,7 @@ def banded_matvec(band: jnp.ndarray, v: jnp.ndarray,
     entries, so the surviving region is bit-identical.
     """
     nb, p = band.shape
-    bp = block_p or _pick_block_padded(p, _targets("banded")[1])
+    bp = block_p or kernel_block_plan("banded", p=p)["block_p"]
     p_pad = _pad_dim(p, bp)
     if p_pad != p:
         band = jnp.pad(band, ((0, 0), (0, p_pad - p)))
@@ -165,7 +173,7 @@ def banded_matmul(band: jnp.ndarray, V: jnp.ndarray,
     :func:`banded_matvec` (fp32 accumulate; output follows the band).
     """
     nb, p = band.shape
-    bp = block_p or _pick_block_padded(p, _targets("banded")[1])
+    bp = block_p or kernel_block_plan("banded", p=p)["block_p"]
     p_pad = _pad_dim(p, bp)
     if p_pad != p:
         band = jnp.pad(band, ((0, 0), (0, p_pad - p)))
@@ -178,9 +186,7 @@ def banded_matmul(band: jnp.ndarray, V: jnp.ndarray,
                    static_argnames=("halfwidth", "block_p", "block_n",
                                     "interpret"))
 def _cov_band_update(x, halfwidth, block_p, block_n, interpret):
-    h = halfwidth
-    xpad = jnp.pad(x, ((0, 0), (h, h)))
-    return cov_band_update_pallas(x, xpad, halfwidth=h, block_p=block_p,
+    return cov_band_update_pallas(x, halfwidth=halfwidth, block_p=block_p,
                                   block_n=block_n, interpret=interpret)
 
 
@@ -201,9 +207,9 @@ def cov_band_update(x: jnp.ndarray, halfwidth: int,
     historical tiling bit-identically.
     """
     n, p = x.shape
-    rt, ft = _targets("cov")
-    bp = block_p or _pick_block_padded(p, ft)
-    bn = block_n or _pick_block_padded(n, rt)
+    plan = kernel_block_plan("cov", rows=n, p=p, halfwidth=halfwidth)
+    bp = block_p or plan["block_p"]
+    bn = block_n or plan["block_n"]
     n_pad, p_pad = _pad_dim(n, bn), _pad_dim(p, bp)
     if (n_pad, p_pad) != (n, p):
         x = jnp.pad(x, ((0, n_pad - n), (0, p_pad - p)))
@@ -216,10 +222,7 @@ def cov_band_update(x: jnp.ndarray, halfwidth: int,
                    static_argnames=("halfwidth", "block_p", "block_n",
                                     "interpret"))
 def _cov_band_update_masked(x, mask, halfwidth, block_p, block_n, interpret):
-    h = halfwidth
-    xpad = jnp.pad(x, ((0, 0), (h, h)))
-    mpad = jnp.pad(mask, ((0, 0), (h, h)))
-    return cov_band_update_masked_pallas(x, xpad, mask, mpad, halfwidth=h,
+    return cov_band_update_masked_pallas(x, mask, halfwidth=halfwidth,
                                          block_p=block_p, block_n=block_n,
                                          interpret=interpret)
 
@@ -246,9 +249,9 @@ def cov_band_update_masked(x: jnp.ndarray, mask: jnp.ndarray, halfwidth: int,
         mask = jnp.broadcast_to(mask[None, :], (n, p))
     if mask.shape != (n, p):
         raise ValueError(f"mask shape {mask.shape} incompatible with {(n, p)}")
-    rt, ft = _targets("cov")
-    bp = block_p or _pick_block_padded(p, ft)
-    bn = block_n or _pick_block_padded(n, rt)
+    plan = kernel_block_plan("cov", rows=n, p=p, halfwidth=halfwidth)
+    bp = block_p or plan["block_p"]
+    bn = block_n or plan["block_n"]
     n_pad, p_pad = _pad_dim(n, bn), _pad_dim(p, bp)
     if (n_pad, p_pad) != (n, p):
         x = jnp.pad(x, ((0, n_pad - n), (0, p_pad - p)))
@@ -286,9 +289,7 @@ def cov_band_update_batched(x: jnp.ndarray, halfwidth: int,
                    static_argnames=("halfwidth", "block_p", "block_n",
                                     "interpret"))
 def _cov_band_update_chunk(x, w, halfwidth, block_p, block_n, interpret):
-    h = halfwidth
-    xpad = jnp.pad(x, ((0, 0), (h, h)))
-    return cov_band_update_chunk_pallas(x, xpad, w, halfwidth=h,
+    return cov_band_update_chunk_pallas(x, w, halfwidth=halfwidth,
                                         block_p=block_p, block_n=block_n,
                                         interpret=interpret)
 
@@ -298,12 +299,9 @@ def _cov_band_update_chunk(x, w, halfwidth, block_p, block_n, interpret):
                                     "interpret"))
 def _cov_band_update_chunk_masked(x, mask, w, halfwidth, block_p, block_n,
                                   interpret):
-    h = halfwidth
-    xpad = jnp.pad(x, ((0, 0), (h, h)))
-    mpad = jnp.pad(mask, ((0, 0), (h, h)))
     return cov_band_update_chunk_masked_pallas(
-        x, xpad, mask, mpad, w, halfwidth=h, block_p=block_p,
-        block_n=block_n, interpret=interpret)
+        x, mask, w, halfwidth=halfwidth, block_p=block_p, block_n=block_n,
+        interpret=interpret)
 
 
 def cov_band_update_chunk(xs: jnp.ndarray, weights: jnp.ndarray,
@@ -335,12 +333,12 @@ def cov_band_update_chunk(xs: jnp.ndarray, weights: jnp.ndarray,
     weights = jnp.asarray(weights, jnp.float32)
     if weights.shape != (K,):
         raise ValueError(f"weights shape {weights.shape} != {(K,)}")
-    rt, ft = _targets("cov")
-    bp = block_p or _pick_block_padded(p, ft)
     # the row tile covers the FLATTENED chunk: a K-round chunk becomes
     # ~K-fold fewer grid cells than K per-round launches (at K=1 the pick
     # degenerates to the per-round choice — bit-identity preserved)
-    bn = block_n or _pick_block_padded(K * n, rt)
+    plan = kernel_block_plan("cov", rows=K * n, p=p, halfwidth=halfwidth)
+    bp = block_p or plan["block_p"]
+    bn = block_n or plan["block_n"]
     itp = _auto_interpret(interpret)
     x = xs.reshape(K * n, p)
     w = jnp.repeat(weights, n)[:, None]                 # (K*n, 1) row weights
@@ -415,9 +413,9 @@ def pca_project(x: jnp.ndarray, w: jnp.ndarray,
     result is bit-identical to the unpadded kernel at the same block sizes.
     """
     n, p = x.shape
-    rt, ft = _targets("stage")
-    bn = block_n or _pick_block_padded(n, rt)
-    bk = block_k or _pick_block_padded(p, ft)
+    plan = kernel_block_plan("stage", rows=n, p=p)
+    bn = block_n or plan["block_n"]
+    bk = block_k or plan["block_p"]
     n_pad, p_pad = _pad_dim(n, bn), _pad_dim(p, bk)
     if (n_pad, p_pad) != (n, p):
         x = jnp.pad(x, ((0, n_pad - n), (0, p_pad - p)))
@@ -444,9 +442,9 @@ def pca_reconstruct(z: jnp.ndarray, w: jnp.ndarray,
     """
     n, q = z.shape
     p = w.shape[0]
-    rt, ft = _targets("stage")
-    bn = block_n or _pick_block_padded(n, rt)
-    bp = block_p or _pick_block_padded(p, ft)
+    plan = kernel_block_plan("stage", rows=n, p=p)
+    bn = block_n or plan["block_n"]
+    bp = block_p or plan["block_p"]
     n_pad, p_pad = _pad_dim(n, bn), _pad_dim(p, bp)
     if (n_pad, p_pad) != (n, p):
         z = jnp.pad(z, ((0, n_pad - n), (0, 0)))
@@ -492,7 +490,7 @@ def supervised_compress(x: jnp.ndarray, w: jnp.ndarray,
         mask = jnp.asarray(mask, jnp.float32)
         if mask.ndim == 1:
             mask = jnp.broadcast_to(mask[None, :], (n, p))
-    bn = block_n or _pick_block_padded(n, _targets("stage")[0])
+    bn = block_n or kernel_block_plan("stage", rows=n, p=p)["block_n"]
     n_pad = _pad_dim(n, bn)
     if n_pad != n:
         x = jnp.pad(x, ((0, n_pad - n), (0, 0)))
@@ -544,7 +542,7 @@ def pca_monitor(x: jnp.ndarray, w: jnp.ndarray,
         mask = jnp.asarray(mask, jnp.float32)
         if mask.ndim == 1:
             mask = jnp.broadcast_to(mask[None, :], (n, p))
-    bn = block_n or _pick_block_padded(n, _targets("stage")[0])
+    bn = block_n or kernel_block_plan("stage", rows=n, p=p)["block_n"]
     n_pad = _pad_dim(n, bn)
     if n_pad != n:
         x = jnp.pad(x, ((0, n_pad - n), (0, 0)))
@@ -643,12 +641,9 @@ def supervised_compress_batched(x: jnp.ndarray, w: jnp.ndarray,
 def _fused_stream(x, mask, w_rows, basis, mean2d, invlam2d, halfwidth,
                   epsilon, with_compress, with_monitor, block_p, block_n,
                   interpret):
-    h = halfwidth
-    xpad = jnp.pad(x, ((0, 0), (h, h)))
-    mpad = jnp.pad(mask, ((0, 0), (h, h)))
     return fused_stream_pallas(
-        x, xpad, mask, mpad, w_rows, basis, mean2d, invlam2d,
-        halfwidth=h, epsilon=epsilon, with_compress=with_compress,
+        x, mask, w_rows, basis, mean2d, invlam2d,
+        halfwidth=halfwidth, epsilon=epsilon, with_compress=with_compress,
         with_monitor=with_monitor, block_p=block_p, block_n=block_n,
         interpret=interpret)
 
@@ -709,23 +704,24 @@ def fused_stream_update(x: jnp.ndarray, weights: jnp.ndarray,
     Returns ``(band, z, x_hat, flagged, t2, spe)`` — band (2h+1, p) fp32;
     z (rows, q); x_hat (rows, p) and bool ``flagged`` (compression, else
     None); t2/spe (rows,) (monitoring, else None).  With fp32 operands
-    the band is bit-identical to :func:`cov_band_update_chunk` at the
-    same blocks and the stages to :func:`supervised_compress` /
-    :func:`pca_monitor`; ``precision="bf16"`` downcasts the tile-load
-    operands (x, mask, basis) to bfloat16 — halving the chunk's HBM
-    traffic — while every accumulator and output stays fp32.
+    the band agrees with :func:`cov_band_update_chunk` and the stages with
+    :func:`supervised_compress` / :func:`pca_monitor` to fp32 rounding;
+    ``precision="bf16"`` downcasts the tile-load operands (x, mask, basis)
+    to bfloat16 — halving the chunk's HBM traffic — while every
+    accumulator and output stays fp32.
 
     The row axis is padded to the block with zero-weight zero-mask rows
     (exact no-ops everywhere), an awkward feature axis is zero-padded to
     the band's feature block exactly like :func:`cov_band_update_chunk`
-    (the stage dots stay at the exact width — the kernel re-slices the
-    halo slab), and every output is sliced back.
+    (basis and mean with zero rows, so the pad columns add exact zeros to
+    the stage dots), and every output is sliced back.
     """
     rows, p = x.shape
     x, mask, basis, mean2d, invlam2d = _fused_prep(
         x, basis, mean, inv_lam, mask, precision)
     weights = jnp.asarray(weights, jnp.float32).reshape(rows, 1)
-    plan = kernel_block_plan("fused", rows=rows, p=p, dtype=precision)
+    plan = kernel_block_plan("fused", rows=rows, p=p, dtype=precision,
+                             halfwidth=halfwidth)
     bp = block_p or plan["block_p"]
     bn = block_n or plan["block_n"]
     rows_pad = _pad_dim(rows, bn)
@@ -734,6 +730,9 @@ def fused_stream_update(x: jnp.ndarray, weights: jnp.ndarray,
         x = jnp.pad(x, ((0, rows_pad - rows), (0, p_pad - p)))
         mask = jnp.pad(mask, ((0, rows_pad - rows), (0, p_pad - p)))
         weights = jnp.pad(weights, ((0, rows_pad - rows), (0, 0)))
+    if p_pad != p:
+        basis = jnp.pad(basis, ((0, p_pad - p), (0, 0)))
+        mean2d = jnp.pad(mean2d, ((0, 0), (0, p_pad - p)))
     out = _fused_stream(x, mask, weights, basis, mean2d, invlam2d,
                         halfwidth, float(epsilon), with_compress,
                         with_monitor, bp, bn, _auto_interpret(interpret))
@@ -741,8 +740,8 @@ def fused_stream_update(x: jnp.ndarray, weights: jnp.ndarray,
     i = 2
     x_hat = flagged = t2 = spe = None
     if with_compress:
-        x_hat = out[i][:rows]
-        flagged = out[i + 1][:rows] > 0.0
+        x_hat = out[i][:rows, :p]
+        flagged = out[i + 1][:rows, :p] > 0.0
         i += 2
     if with_monitor:
         t2 = out[i][:rows, 0]
@@ -764,12 +763,10 @@ def fused_stream_stages_blocked(x: jnp.ndarray, basis: jnp.ndarray,
                                            jnp.ndarray | None,
                                            jnp.ndarray | None]:
     """The fused kernel's STAGE arithmetic as a plain-jnp scan over row
-    blocks — same tile shapes, same op order, same fp32 accumulation as
-    the kernel body, and therefore (in interpret mode) the same bits.
-    A ``lax.scan`` (not an unrolled python loop) mirrors the interpret
-    grid loop structurally: unrolling lets XLA fuse across blocks and
-    re-vectorize the SPE reduction, which drifts bits at multi-block
-    shapes.
+    blocks — the kernel's row blocks, same op order, same fp32
+    accumulation as the kernel body, so it agrees with the kernel's
+    stages to fp32 rounding.  A ``lax.scan`` (not an unrolled python loop)
+    mirrors the grid loop structurally.
 
     This is the post-refresh fix-up of the fused driver path
     (:func:`repro.streaming.driver.chunk_stream_step`): the kernel runs
@@ -787,7 +784,8 @@ def fused_stream_stages_blocked(x: jnp.ndarray, basis: jnp.ndarray,
     rows, p = x.shape
     x, mask, basis, mean2d, invlam2d = _fused_prep(
         x, basis, mean, inv_lam, mask, precision)
-    bn = block_n or _pick_block_padded(rows, _targets("fused", precision)[0])
+    bn = block_n or kernel_block_plan("fused", rows=rows, p=p,
+                                      dtype=precision)["block_n"]
     rows_pad = _pad_dim(rows, bn)
     if rows_pad != rows:
         x = jnp.pad(x, ((0, rows_pad - rows), (0, 0)))

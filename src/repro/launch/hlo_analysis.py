@@ -11,10 +11,9 @@ Wire-byte model (per device, ring algorithms — the XLA default on ICI):
     all-to-all          N * (g-1)/g
     collective-permute  N
 
-Hardware constants (TPU v5e target):
-    peak bf16 compute   197 TFLOP/s per chip
-    HBM bandwidth       819 GB/s per chip
-    ICI link bandwidth  ~50 GB/s per link
+Hardware constants: :data:`DEVICE_PEAKS`, keyed by ``Device.device_kind``;
+the dry-run and the static certifier price programs for the v5e
+(:data:`TARGET_KIND`).
 """
 
 from __future__ import annotations
@@ -25,11 +24,42 @@ import re
 
 __all__ = ["CollectiveStats", "parse_collectives", "RooflineTerms",
            "roofline_terms", "fallback_trip", "ring_wire_bytes",
+           "DevicePeaks", "DEVICE_PEAKS", "TARGET_KIND", "device_peaks",
            "PEAK_FLOPS", "HBM_BW", "ICI_BW"]
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks."""
+
+    flops: float                 # bf16 FLOP/s
+    hbm_bw: float                # HBM bytes/s
+    ici_bw: float                # inter-chip bytes/s per link
+
+
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect per chip over 4 links (50 GB/s each).
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+# the chip the dry-run and the static resource certifier describe
+TARGET_KIND = "TPU v5 lite"
+
+
+def device_peaks(kind: str) -> DevicePeaks:
+    """The peaks of a ``device_kind``; a device not in the table raises."""
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind {kind!r} "
+                         f"(known: {sorted(DEVICE_PEAKS)})") from None
+
+
+PEAK_FLOPS = device_peaks(TARGET_KIND).flops
+HBM_BW = device_peaks(TARGET_KIND).hbm_bw
+ICI_BW = device_peaks(TARGET_KIND).ici_bw
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

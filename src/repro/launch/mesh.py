@@ -9,9 +9,18 @@ initialization and then calls these.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "make_fleet_mesh",
            "mesh_axis_sizes"]
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with every axis ``Auto``: the drivers leave layout
+    between their shard_map regions to the partitioner (the default axis
+    type is ``Explicit``, which makes sharded results reject unannotated
+    gathers)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,12 +31,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many local devices exist (tests/examples)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_fleet_mesh(region: int | None = None, data: int = 1):
@@ -39,7 +48,7 @@ def make_fleet_mesh(region: int | None = None, data: int = 1):
     --xla_force_host_platform_device_count=N`` forced before jax init).
     """
     n = (jax.device_count() // data) if region is None else region
-    return jax.make_mesh((n, data), ("region", "data"))
+    return _auto_mesh((n, data), ("region", "data"))
 
 
 def mesh_axis_sizes(mesh) -> dict[str, int]:

@@ -1,56 +1,55 @@
-"""Roofline-informed kernel tile targets (per backend, per dtype).
+"""Kernel tile targets (per backend, per dtype).
 
-The kernel wrappers in :mod:`repro.kernels.ops` used to hard-code their
-block-size targets (rows 128, features 512 — the shapes the kernels were
-first tuned at).  This module derives the targets instead, from the same
-machine model :mod:`repro.launch.hlo_analysis` uses for the dry-run
-roofline (:data:`PEAK_FLOPS` / :data:`HBM_BW` of a v5e core) plus the VMEM
-capacity, so a backend with different balance points picks different tiles
-without touching kernel code.
+The kernel wrappers in :mod:`repro.kernels.ops` take their block-size
+targets from here (through :func:`repro.kernels.ops.kernel_block_plan`), so
+a backend with different constraints picks different tiles without touching
+kernel code.
 
-Derivation (TPU branch):
+TPU branch (v5e):
 
-* the minimum profitable tile is the register-file native shape — (8, 128)
-  sublanes × lanes at fp32, (16, 128) at bf16 (packed sublanes);
-* the row-block target is sized so a double-buffered working set of the
-  fused kernel (x + halo slab + stage outputs, ~4 (block_n, p)-sized tiles
-  in flight) stays under half of VMEM at the largest supported feature
-  width — rounded down to a power of two;
-* the feature target keeps the arithmetic intensity of the band fold above
-  the HBM ridge point (FLOPs/byte = PEAK_FLOPS / HBM_BW): each band
-  product reads 8 bytes/feature and does 2·(2h+1) FLOPs, so wider feature
-  tiles only help until the slab exceeds VMEM — the cap lands at the
-  historical 512 for fp32 and doubles for bf16 (half the bytes per lane).
+* blocks are whole register tiles — (8, 128) sublanes × lanes at fp32,
+  (16, 128) at bf16 (packed sublanes): the feature block is a multiple of
+  128 and the row block a multiple of 8 (16), and the wrappers pad p and
+  the row count up to them;
+* the feature target is 512 lanes at fp32 and double at bf16 (half the
+  bytes per lane): the band fold reads 8 bytes per feature for 2·(2h+1)
+  FLOPs, so it is bandwidth-bound and takes slabs as wide as the byte
+  budget allows;
+* the row target is the largest power of two whose double-buffered blocks
+  fit half of :data:`VMEM_BYTES` (the other half holds the in-kernel
+  values: the halo window and its rotations, the stage temporaries and the
+  band accumulator).  The blocks of one row are counted per kernel family:
+  the x and mask tiles, the x and mask halo slabs — the whole padded row
+  plus both halos, re-read for every feature block — and the fp32 x_hat
+  and flag rows of the fused stages.
 
-Non-TPU backends (the CPU CI container runs every kernel in interpret
-mode) return the historical targets unchanged, so every existing result is
-bit-identical: tiling is part of the accumulation order, and the
-differential suites pin bits, not just values.
+The CPU backend runs every kernel in interpret mode (tests) and keeps the
+historical targets (rows 128, features 512) with no alignment, so
+interpret-mode results do not move with this table.  Any other backend is
+an error: there is no tiling for it.
 """
 
 from __future__ import annotations
 
 import jax
 
-from repro.launch.hlo_analysis import HBM_BW, PEAK_FLOPS
+__all__ = ["VMEM_BYTES", "MIN_TILE", "block_targets"]
 
-__all__ = ["VMEM_BYTES", "MIN_TILE", "RIDGE_FLOPS_PER_BYTE",
-           "block_targets"]
-
-# v5e per-core VMEM (the budget the fused kernel's working set must fit)
+# the scoped VMEM a Mosaic kernel may use by default on v5e — the budget
+# the double-buffered blocks plus the in-kernel values must fit
 VMEM_BYTES = 16 * 2 ** 20
-
-# the HBM ridge point of the machine model: an op under this arithmetic
-# intensity is bandwidth-bound regardless of tile shape — which the band
-# fold (2·(2h+1) FLOPs per 8 bytes) always is, hence width-greedy slabs
-RIDGE_FLOPS_PER_BYTE = PEAK_FLOPS / HBM_BW
 
 # native register tile (sublanes, lanes) per dtype byte-width
 MIN_TILE = {4: (8, 128), 2: (16, 128)}
 
-# the shapes the kernels were tuned at before this module existed — every
-# non-TPU backend keeps them so interpret-mode results stay bit-identical
-_HISTORICAL = {"rows": 128, "features": 512}
+# the shapes the kernels were tuned at in interpret mode (CPU)
+_HISTORICAL = {"rows": 128, "features": 512, "row_align": 1,
+               "feature_align": 1}
+
+# per block row: (tiles block_p wide, halo slabs p_pad + halo wide — both
+# in the tile dtype; fp32 stage output rows p_pad wide)
+_ROW_BLOCKS = {"cov": (2, 2, 0), "fused": (2, 2, 2), "stage": (2, 0, 2),
+               "banded": (2, 0, 0)}
 
 
 def _dtype_bytes(dtype: str) -> int:
@@ -58,39 +57,41 @@ def _dtype_bytes(dtype: str) -> int:
 
 
 def block_targets(kind: str, dtype: str = "fp32",
-                  backend: str | None = None) -> dict[str, int]:
-    """Tile-size targets ``{"rows": ..., "features": ...}`` for a kernel
-    family.
+                  backend: str | None = None, *,
+                  block_p: int | None = None, p_pad: int | None = None,
+                  halo: int = 0) -> dict[str, int]:
+    """Tile-size targets for a kernel family: ``{"rows", "features",
+    "row_align", "feature_align"}``.
 
     ``kind`` names the wrapper family (``"cov"``, ``"stage"``,
-    ``"fused"``, ``"banded"`` — they share the row/feature split);
-    ``dtype`` the tile-load dtype (``"fp32"``/``"bf16"``); ``backend``
-    overrides the detected JAX backend (tests pass ``"tpu"`` explicitly —
-    the CI container is CPU-only).
+    ``"fused"``, ``"banded"``); ``dtype`` the tile-load dtype
+    (``"fp32"``/``"bf16"``); ``backend`` overrides the detected JAX
+    backend (the described-TPU compile tests pass ``"tpu"``).  On TPU the
+    row target counts the blocks of one row at the chosen ``block_p``
+    (default: the feature target) and padded width ``p_pad`` (default:
+    ``block_p``), with ``halo`` extra slab columns (both halos).
 
-    The returned numbers are *targets*: the wrappers still clamp to exact
-    divisors where that preserves historical bit-exactness, and pad
-    otherwise (:func:`repro.kernels.ops._pick_block_padded`).
+    The returned numbers are *targets*: the wrappers still prefer exact
+    divisors and pad otherwise (:func:`repro.kernels.ops._pick_block_padded`),
+    always to a multiple of the alignment.
     """
-    if kind not in ("cov", "stage", "fused", "banded"):
+    if kind not in _ROW_BLOCKS:
         raise ValueError(f"unknown kernel family {kind!r}")
     be = backend or jax.default_backend()
-    if be != "tpu":
+    if be == "cpu":
         return dict(_HISTORICAL)
+    if be != "tpu":
+        raise ValueError(f"no kernel tiling for backend {be!r}")
     nbytes = _dtype_bytes(dtype)
     sub, lanes = MIN_TILE[nbytes]
-    # feature target: the band fold reads 8 bytes/feature for 2·(2h+1)
-    # FLOPs, far under the ridge point (PEAK_FLOPS / HBM_BW), so the fold
-    # is bandwidth-bound at any width — the slab goes as wide as the byte
-    # budget allows: the historical 512 lanes at fp32, double at bf16
-    # (half the bytes per lane buys double the features per slab)
     features = 512 * (4 // nbytes)
-    # row target: largest power of two whose double-buffered working set
-    # (~4 (rows, features) tiles in flight: x + halo slab + stage outputs)
-    # still fits half of VMEM
+    bp = block_p or features
+    width = p_pad or bp
+    tiles, slabs, stage_rows = _ROW_BLOCKS[kind]
+    row_bytes = 2 * (nbytes * (tiles * bp + slabs * (width + halo))
+                     + 4 * stage_rows * width)          # double-buffered
     rows = sub
-    while (4 * 2 * (2 * rows) * features * nbytes <= VMEM_BYTES // 2
-           and rows < 1024):
+    while 2 * rows * row_bytes <= VMEM_BYTES // 2 and rows < 1024:
         rows *= 2
-    return {"rows": max(rows, _HISTORICAL["rows"]),
-            "features": max(features, lanes)}
+    return {"rows": rows, "features": features, "row_align": sub,
+            "feature_align": lanes}

@@ -20,7 +20,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.distributed.sharding import current_mesh, shard_activation
@@ -108,14 +107,14 @@ def _moe_ep_shard_map(p, cfg, x, top_p, top_e, mesh, dp_axes, nm, g=None):
         y = jax.lax.psum(y[:-1], "model")                     # the ONE AR
         return y.reshape(Bl, S, d)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(PartitionSpec(dp_spec, None, None),
                   PartitionSpec(dp_spec, None, None),
                   PartitionSpec(dp_spec, None, None),
                   w_spec, w_spec, w_spec),
         out_specs=PartitionSpec(dp_spec, None, None),
-        check_rep=False,
+        check_vma=False,
     )(x, top_p, top_e, p["w_gate"], p["w_up"], p["w_down"])
 
 

@@ -430,11 +430,11 @@ class StreamingPCAEngine:
         # double-buffered staging (DESIGN.md Sec. 17): two pinned,
         # engine-owned host buffers filled alternately; every upload is an
         # EXPLICIT OWNED COPY (jnp.asarray(copy=True)), so the device batch
-        # never aliases staging memory — refilling a buffer two chunks
-        # later cannot corrupt an in-flight batch (the CPU device_put
-        # aliasing hazard, pinned by the poisoning regression test).  The
-        # per-buffer transfer fence (_uploads) is waited on before a
-        # REFILL — a wait on the copy-out, never on the chunk fold.
+        # never aliases staging memory (the CPU device_put aliasing hazard,
+        # pinned by the poisoning regression test).  The transfer reads the
+        # host buffer asynchronously, so the per-buffer transfer fence
+        # (_uploads) is waited on before a REFILL — a wait on the copy-out,
+        # never on the chunk fold.
         self._host_bufs: list[np.ndarray | None] = [None, None]
         self._mask_bufs: list[np.ndarray | None] = [None, None]
         self._uploads: list[tuple | None] = [None, None]
@@ -706,9 +706,9 @@ class StreamingPCAEngine:
 
     def _upload(self, host_buf: np.ndarray) -> jax.Array:
         """Owned-copy upload: the device buffer never aliases the pinned
-        staging memory (``copy=True`` forces the copy the CPU backend
-        would elide for aligned host arrays), so the buffer is free to be
-        refilled once the copy-out fence clears."""
+        staging memory.  The copy is made by the transfer, which reads the
+        host buffer asynchronously, so the buffer is free to be refilled
+        only once the copy-out fence (the upload's readiness) clears."""
         return jnp.asarray(host_buf, copy=True)
 
     def _stage(self) -> _StagedChunk:

@@ -157,6 +157,22 @@ def _metrics_template(cfg: "StreamConfig") -> RoundMetrics:
                         compression=comp, detection=det)
 
 
+def _fp32_matmuls(step):
+    """Trace ``step`` with every fp32 matmul at full fp32 precision.
+
+    The TPU's default precision rounds fp32 matmul inputs to bf16: on the
+    chip that left the refreshed basis ~1e-3 off orthonormal (the
+    retained fraction of the wsn-1m region shape moved by up to 1.9e-3)
+    and would round the forgetting-weighted mean sums the same way.  On
+    the CPU the setting changes nothing.
+    """
+    @functools.wraps(step)
+    def traced(*args, **kwargs):
+        with jax.default_matmul_precision("float32"):
+            return step(*args, **kwargs)
+    return traced
+
+
 def stream_init(cfg: StreamConfig, key: jax.Array,
                 dtype=jnp.float32) -> StreamState:
     return StreamState(
@@ -168,6 +184,7 @@ def stream_init(cfg: StreamConfig, key: jax.Array,
     )
 
 
+@_fp32_matmuls
 def stream_step(cfg: StreamConfig, state: StreamState, x_round: jnp.ndarray,
                 mask: jnp.ndarray | None = None,
                 ) -> tuple[StreamState, RoundMetrics]:
@@ -237,6 +254,7 @@ def stream_step(cfg: StreamConfig, state: StreamState, x_round: jnp.ndarray,
     return new, metrics
 
 
+@_fp32_matmuls
 def chunk_stream_step(cfg: StreamConfig, state: StreamState,
                       x_chunk: jnp.ndarray,
                       masks: jnp.ndarray | None = None,
@@ -273,8 +291,8 @@ def chunk_stream_step(cfg: StreamConfig, state: StreamState,
     are speculated against the pre-decision basis (bit-identical to the
     post-decision basis whenever the scheduler does not fire — the
     refresh is a select); on the refresh rounds a pure-jnp twin
-    (:func:`repro.kernels.ops.fused_stream_stages_blocked`, bitwise equal
-    to the kernel's stage arithmetic) recomputes them against the rotated
+    (:func:`repro.kernels.ops.fused_stream_stages_blocked`, the kernel's
+    stage arithmetic to fp32 rounding) recomputes them against the rotated
     basis under ``lax.cond``.  At fp32 the fused path is bit-identical to
     the split path; ``cfg.precision="bf16"`` halves the kernel's tile
     traffic (fp32 accumulation) at tolerance-level divergence — note the
@@ -635,15 +653,12 @@ def sharded_stream_run(cfg: StreamConfig, mesh, states: StreamState,
     """The batched run with the networks axis sharded over ``axis``.
 
     Each device streams its local slice of the fleet; no collective touches
-    per-network state (checked with ``check_rep=False`` because the body is
+    per-network state (checked with ``check_vma=False`` because the body is
     collective-free by construction).  Requires the number of networks to be
     divisible by the axis size.  ``chunk``/``probe_every`` thread through to
     :func:`batched_stream_run` per shard (the chunked body is just as
     collective-free).
     """
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec
-
     from repro.distributed.sharding import network_axis_spec
 
     spec = network_axis_spec(mesh, axis)
@@ -658,13 +673,13 @@ def sharded_stream_run(cfg: StreamConfig, mesh, states: StreamState,
         return batched_stream_run(cfg, states_l, xs_l, chunk=chunk,
                                   probe_every=probe_every)
 
-    fm = shard_map(
+    fm = jax.shard_map(
         local_run, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: spec, states),
                   spec),
         out_specs=(jax.tree.map(lambda _: spec, states),
                    jax.tree.map(lambda _: spec, _metrics_template(cfg))),
-        check_rep=False,
+        check_vma=False,
     )
     return fm(states, xs)
 
@@ -757,10 +772,10 @@ _contracts.register(_contracts.Contract(
            _jl.NoF64(),
            _res.VmemBudget(),
            # one tile-load per chunk: the whole entry moves exactly one
-           # pass of HBM traffic, and the chunk data/mask tiles in
-           # particular are never re-fetched across feature blocks
+           # pass of HBM traffic, and the chunk data/mask halo slabs in
+           # particular are read once (one feature block at these widths)
            _res.HbmTrafficBudget(max_passes=1.0,
-                                 single_pass=("x_ref", "m_ref"))),
+                                 single_pass=("xpad_ref", "mpad_ref"))),
 ))
 
 _contracts.register(_contracts.Contract(
@@ -774,7 +789,7 @@ _contracts.register(_contracts.Contract(
            _jl.NoF64(),
            _res.VmemBudget(),
            _res.HbmTrafficBudget(max_passes=1.0,
-                                 single_pass=("x_ref", "m_ref"))),
+                                 single_pass=("xpad_ref", "mpad_ref"))),
 ))
 
 _contracts.register(_contracts.Contract(

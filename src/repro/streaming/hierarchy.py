@@ -170,10 +170,11 @@ def hierarchical_stream_run(cfg: StreamConfig, mesh, states: StreamState,
     streams its local regions through :func:`batched_stream_run` (with the
     PR 5 chunk/probe_every knobs threaded through) with NO cross-shard
     traffic, then the merge runs as the run's only collectives: one tiled
-    ``all_gather`` of the (q_local,) energy records and ONE multi-operand
-    ``psum`` carrying both the trace partials and the refresh-boundary
-    flags (contract ``hierarchy.refresh`` pins this budget at the jaxpr
-    level — see the registration at the bottom of this module).
+    ``all_gather`` of the (q_local,) energy records and one ``psum`` call
+    over the trace partials and the refresh-boundary flags, which JAX
+    binds as one psum per operand (contract ``hierarchy.refresh`` pins
+    this budget at the jaxpr level — see the registration at the bottom of
+    this module).
 
     Returns ``(final_states, metrics, fleet)`` where states/metrics are the
     per-region leaves of the flat driver (regions-leading) and ``fleet``
@@ -183,7 +184,6 @@ def hierarchical_stream_run(cfg: StreamConfig, mesh, states: StreamState,
     ``c_regions`` (default ``cfg.c_max``), ARQ-scaled like every
     intra-network packet.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     from repro.distributed.sharding import region_axis_spec
@@ -209,10 +209,11 @@ def hierarchical_stream_run(cfg: StreamConfig, mesh, states: StreamState,
         # level-2 records: per-region energies + trace partials
         lam_l, den_l = jax.vmap(region_energies)(fin)
         lam_table = jax.lax.all_gather(lam_l, axis, tiled=True)
-        # ONE multi-operand psum (a single collective on the wire) carries
-        # both the trace partials and the per-boundary refresh flags; the
-        # flags' fleet-wide sum books one merge per decision boundary at
-        # which ANY region refreshed, plus the final merge when none fired
+        # one psum call over the trace partials and the per-boundary
+        # refresh flags (JAX binds it leaf by leaf: two psum equations);
+        # the flags' fleet-wide sum books one merge per decision boundary
+        # at which ANY region refreshed, plus the final merge when none
+        # fired
         total_var, fired = jax.lax.psum(
             (jnp.sum(den_l),
              jnp.sum(metrics.did_refresh.astype(jnp.float32), axis=0)),
@@ -233,12 +234,13 @@ def hierarchical_stream_run(cfg: StreamConfig, mesh, states: StreamState,
         merge_epochs=rep, merge_packets=rep)
     out_specs = (state_specs, metric_specs, fleet_specs)
     if masks is None:
-        fm = shard_map(local_run, mesh=mesh, in_specs=(state_specs, spec),
-                       out_specs=out_specs, check_rep=False)
+        fm = jax.shard_map(local_run, mesh=mesh,
+                           in_specs=(state_specs, spec),
+                           out_specs=out_specs, check_vma=False)
         return fm(states, xs)
-    fm = shard_map(local_run, mesh=mesh,
-                   in_specs=(state_specs, spec, spec),
-                   out_specs=out_specs, check_rep=False)
+    fm = jax.shard_map(local_run, mesh=mesh,
+                       in_specs=(state_specs, spec, spec),
+                       out_specs=out_specs, check_vma=False)
     return fm(states, xs, masks)
 
 
@@ -271,12 +273,12 @@ def _trace_hierarchy_refresh():
 _contracts.register(_contracts.Contract(
     id="hierarchy.refresh",
     where="repro.streaming.hierarchy.hierarchical_stream_run",
-    claim="exactly one all_gather and one (multi-operand) psum on the "
-          "'region' axis per run, none inside loop bodies, no other "
-          "cross-host collectives anywhere (PR 6)",
+    claim="exactly one all_gather and two psums (trace partial, refresh "
+          "flags) on the 'region' axis per run, none inside loop bodies, "
+          "no other cross-host collectives anywhere",
     trace=_trace_hierarchy_refresh,
     rules=(_jl.CollectiveBudget(axis="region",
-                                budgets=(("all_gather", 1), ("psum", 1))),
+                                budgets=(("all_gather", 1), ("psum", 2))),
            _jl.ForbidInLoops(),
            _jl.NoF64(),
            _res.VmemBudget(),

@@ -38,6 +38,7 @@ def relaunch_in_worker(test_file: str, n_devices: int = 8,
     env = dict(os.environ)
     env[ENV_FLAG] = "1"
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_devices}")
+    env["JAX_PLATFORMS"] = "cpu"     # forced host devices; never the chip
     src = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

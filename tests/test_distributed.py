@@ -113,17 +113,16 @@ class TestHaloExchange:
         """halo_exchange on a 1-element axis: no neighbors -> zeros."""
         from repro.core.aggregation import halo_exchange
         mesh = jax.make_mesh((1,), ("p",))
-        from jax.experimental.shard_map import shard_map
 
         def f(x):
             l, r = halo_exchange(x, 2, "p")
             return l, r
 
         x = jnp.arange(8.0).reshape(1, 8)
-        fm = shard_map(f, mesh=mesh,
-                       in_specs=PartitionSpec("p", None),
-                       out_specs=(PartitionSpec("p", None),
-                                  PartitionSpec("p", None)))
+        fm = jax.shard_map(f, mesh=mesh,
+                           in_specs=PartitionSpec("p", None),
+                           out_specs=(PartitionSpec("p", None),
+                                      PartitionSpec("p", None)))
         l, r = fm(x)
         np.testing.assert_array_equal(np.asarray(l), np.zeros((1, 2)))
         np.testing.assert_array_equal(np.asarray(r), np.zeros((1, 2)))
@@ -137,7 +136,6 @@ class TestPipeline:
 
     def test_single_stage_identity(self):
         """With one stage the pipeline is just layer_fn over microbatches."""
-        from jax.experimental.shard_map import shard_map
         from repro.distributed.pipeline import pipeline_apply
         mesh = jax.make_mesh((1,), ("pipe",))
         w = jnp.asarray(np.random.default_rng(0).normal(size=(8, 8))
@@ -152,9 +150,9 @@ class TestPipeline:
             return pipeline_apply(layer, p, h, n_microbatches=2,
                                   axis_name="pipe")
 
-        fm = shard_map(run, mesh=mesh,
-                       in_specs=(PartitionSpec(), PartitionSpec()),
-                       out_specs=PartitionSpec(), check_rep=False)
+        fm = jax.shard_map(run, mesh=mesh,
+                           in_specs=(PartitionSpec(), PartitionSpec()),
+                           out_specs=PartitionSpec(), check_vma=False)
         out = fm(w, x)
         np.testing.assert_allclose(np.asarray(out),
                                    np.tanh(np.asarray(x) @ np.asarray(w)),

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -313,7 +314,11 @@ class TestNoAliasing:
         eng = StreamingPCAEngine(_cfg(), slots=1, seed=0)
         host = np.ones((4, 4), np.float32)
         dev = eng._upload(host)
-        host[:] = 777.0                    # poison immediately after upload
+        # the transfer reads the host buffer asynchronously; the engine
+        # waits on it (the transfer fence) before any refill, and so does
+        # this poisoning — after that the device copy must not alias host
+        jax.block_until_ready(dev)
+        host[:] = 777.0
         np.testing.assert_array_equal(np.asarray(dev),
                                       np.ones((4, 4), np.float32))
 
@@ -331,9 +336,14 @@ class TestNoAliasing:
             while eng.step() or eng.queue:
                 if poison:
                     # scribble over BOTH pinned staging buffers right
-                    # after the step dispatched its uploads: owned-copy
-                    # uploads mean the in-flight device batches (and the
-                    # prestaged chunk, in pipelined mode) must not move
+                    # after the step, once their uploads' transfer fences
+                    # cleared (the wait _stage makes before a refill):
+                    # owned-copy uploads mean the in-flight device batches
+                    # (and the prestaged chunk, in pipelined mode) must
+                    # not move
+                    for up in eng._uploads:
+                        if up is not None:
+                            jax.block_until_ready(up)
                     for buf in eng._host_bufs + eng._mask_bufs:
                         if buf is not None:
                             buf.fill(np.float32(1e9))

@@ -163,3 +163,41 @@ class TestRoofline:
         for arch, shape, why in skips:
             assert shape == "long_500k"
             assert "sub-quadratic" in why
+
+
+class TestDevicePeaks:
+    def test_peaks_keyed_by_device_kind(self):
+        v5e = H.device_peaks("TPU v5 lite")
+        assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+        assert (H.PEAK_FLOPS, H.HBM_BW) == (v5e.flops, v5e.hbm_bw)
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v4", ""])
+    def test_unknown_device_raises(self, kind):
+        with pytest.raises(ValueError, match="no published peaks"):
+            H.device_peaks(kind)
+
+
+class TestCompileCache:
+    def test_env_var_wins_and_nothing_else_is_set(self, monkeypatch,
+                                                   tmp_path):
+        import jax
+
+        from repro.launch import compile_cache
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv(compile_cache.CACHE_ENV, str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_a_fixed_path_in_the_checkout(self, monkeypatch):
+        import jax
+
+        from repro.launch import compile_cache
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.delenv(compile_cache.CACHE_ENV, raising=False)
+        try:
+            path = compile_cache.enable_compile_cache()
+            assert path == compile_cache.enable_compile_cache()
+            assert path.endswith(".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)

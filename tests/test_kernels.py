@@ -46,6 +46,22 @@ def _check(a, b, dtype, scale: float = 1.0):
         atol=atol, rtol=rtol)
 
 
+class TestBackendSelection:
+    """Kernels compile on a TPU, run interpreted on the CPU, and refuse any
+    other backend instead of silently interpreting there."""
+
+    @pytest.mark.parametrize("backend,want", [("tpu", False), ("cpu", True)])
+    def test_auto_interpret(self, monkeypatch, backend, want):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert ops._auto_interpret(None) is want
+        assert ops._auto_interpret(not want) is (not want)   # explicit wins
+
+    def test_unknown_backend_raises(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(ValueError, match="gpu"):
+            ops._auto_interpret(None)
+
+
 class TestBandedMatvec:
     @pytest.mark.parametrize("p,h,block_p", [
         (128, 1, 64), (256, 4, 128), (512, 8, 128), (384, 16, 128),

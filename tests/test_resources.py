@@ -86,7 +86,6 @@ def _trace_merge(q_gathered: int):
     """The hierarchy merge shape in miniature: ONE tiled all_gather of a
     (1, q) energy record + ONE psum of the scalar trace partial, on the
     'region' mesh axis."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = jax.make_mesh((1,), ("region",))
@@ -96,8 +95,9 @@ def _trace_merge(q_gathered: int):
         total = jax.lax.psum(jnp.sum(den), "region")
         return table, total
 
-    fn = shard_map(local, mesh=mesh, in_specs=(P("region"), P("region")),
-                   out_specs=(P(), P()), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P("region"), P("region")),
+                       out_specs=(P(), P()), check_vma=False)
     return jax.make_jaxpr(fn)(jnp.zeros((1, q_gathered), jnp.float32),
                               jnp.zeros((1,), jnp.float32))
 

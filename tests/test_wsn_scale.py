@@ -59,6 +59,7 @@ class TestDryrunSmoke:
         out = tmp_path / "dryrun_smoke.jsonl"
         env = dict(os.environ)
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        env["JAX_PLATFORMS"] = "cpu"     # forced host devices, not the chip
         env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep \
             + env.get("PYTHONPATH", "")
         proc = subprocess.run(
