@@ -35,9 +35,12 @@ Admission runs through a priority queue front end
 oldest-first within a priority, per-tenant concurrent-slot quotas, and a
 bounded queue that rejects (backpressures) external submits when full.
 Structured telemetry (:class:`repro.serve.telemetry.TelemetryRecorder`)
-records per-step wall time, staged-vs-compute overlap, queue depth,
-admissions/retirements and per-slot bills into a ring buffer with an
-optional JSONL sink.
+records per-step wall time and host phase times, staged-vs-compute
+overlap, queue depth, admissions/retirements (with each stream's queue
+wait and service time) and per-slot bills into a ring buffer with an
+optional JSONL sink.  The host phases are ``engine.*`` profiler spans and
+the engine's own device programs run under ``pca.admit``, ``pca.books``
+and ``pca.retire`` named scopes, so a trace puts names on both.
 
 The streaming engine is fault-aware (DESIGN.md Sec. 9): each slot carries a
 :class:`repro.runtime.health.HealthMonitor` driven by a *logical* clock (one
@@ -68,7 +71,7 @@ from repro.models import transformer as T
 from repro.runtime.elastic import RescalePlan, plan_mesh
 from repro.runtime.health import HealthMonitor, StragglerPolicy
 from repro.serve.queue import AdmissionQueue, QueuePolicy
-from repro.serve.telemetry import StepRecord, TelemetryRecorder
+from repro.serve.telemetry import PhaseTimer, StepRecord, TelemetryRecorder
 from repro.streaming.driver import (StreamConfig, StreamState,
                                     engine_chunk_step_fn, stream_init)
 
@@ -235,6 +238,12 @@ class StreamRequest:
     retirements: list = dataclasses.field(default_factory=list)
     # engine-internal: round to resume from after a revival re-admission
     resume_at: int = 0
+    # perf_counter stamps of the current segment: when it entered the
+    # queue (a revival continuation re-enters it), when a slot took it,
+    # and when its result was built
+    submitted_s: float | None = None
+    admitted_s: float | None = None
+    finished_s: float | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -307,6 +316,7 @@ def _slot_summary_fn(cfg: StreamConfig):
                                             online_total_variance)
     from repro.streaming.scheduler import retained_fraction
 
+    @jax.named_scope("pca.retire")
     def summarize(states, comp, det, i):
         st = jax.tree.map(lambda a: a[i], states)
         out = dict(
@@ -327,6 +337,57 @@ def _slot_summary_fn(cfg: StreamConfig):
         return out
 
     return jax.jit(summarize)
+
+
+@functools.lru_cache(maxsize=None)
+def _admit_splice_fn(slots: int):
+    """The admission splice as one jitted program: fresh states for the
+    FULL fleet selected per slot by a (slots,) mask, and the admitted
+    slots' device books zeroed.  Fixed shapes, so one compile ever: a
+    variable-length ``at[idx].set`` would retrace once per distinct admit
+    count.  ``jnp.where`` writes the identical values, so admission stays
+    bit-identical.  The fleet state is donated: the splice updates it in
+    place, as the chunk step does."""
+
+    @jax.named_scope("pca.admit")
+    def splice(states, fresh, comp_books, det_books, mask):
+        def leaf(full, f):
+            sel = mask.reshape((slots,) + (1,) * (f.ndim - 1))
+            return jnp.where(sel, f, full)
+
+        def zero(books):
+            return tuple(jnp.where(mask, 0.0, b) for b in books)
+
+        return (jax.tree.map(leaf, states, fresh), zero(comp_books),
+                zero(det_books))
+
+    return jax.jit(splice, donate_argnums=(0,))
+
+
+@functools.lru_cache(maxsize=None)
+def _books_fn(with_compression: bool, with_detection: bool,
+              alarm_price: float):
+    """Fold one step's stage outputs into the per-slot device books, as
+    one jitted program.  Idle slots fold zero rounds: they are masked out
+    (where, not multiply — robust to any NaN in an idle slot)."""
+
+    @jax.named_scope("pca.books")
+    def books(comp_books, det_books, comp, det, live):
+        on = live > 0
+        if with_compression:
+            max_err, extras, bits = comp_books
+            comp_books = (
+                jnp.maximum(max_err, jnp.where(on, comp.max_err, 0.0)),
+                extras + jnp.where(on, comp.extra_packets, 0.0),
+                bits + jnp.where(on, comp.bits_on_air, 0.0))
+        if with_detection:
+            events, alarm_packets = det_books
+            alarms = jnp.where(on, det.alarms, 0.0)
+            det_books = (events + alarms,
+                         alarm_packets + alarms * alarm_price)
+        return comp_books, det_books
+
+    return jax.jit(books)
 
 
 @dataclasses.dataclass
@@ -440,6 +501,7 @@ class StreamingPCAEngine:
         self._uploads: list[tuple | None] = [None, None]
         self._parity = 0
         self._staged: _StagedChunk | None = None
+        self._phases = PhaseTimer()      # host phase times of this step
         # hot-loop hygiene counters (checked by the engine.step.pipelined
         # contract): every device→host conversion in the engine goes
         # through _pull with a ledger key — "hot" must stay 0 forever
@@ -455,9 +517,9 @@ class StreamingPCAEngine:
         # last_compression keeps the most recent round's full device output
         # (scores, sink view, flags) for observability — one round's
         # arrays, bounded.
-        self._comp_max_err = jnp.zeros(slots, jnp.float32)
-        self._comp_extras = jnp.zeros(slots, jnp.float32)
-        self._comp_bits = jnp.zeros(slots, jnp.float32)
+        zeros = functools.partial(jnp.zeros, slots, jnp.float32)
+        self._comp_books: tuple = ((zeros(), zeros(), zeros())
+                                   if cfg.compression is not None else ())
         self.last_compression = None
         # T²/SPE detection accounting (cfg.detection only): per-slot running
         # alarmed-epoch count and alarm-flood bill for the current segment,
@@ -465,9 +527,10 @@ class StreamingPCAEngine:
         # keeps the most recent round's device output for observability.
         # The per-alarm packet price and ARQ factor are engine-lifetime
         # constants (cfg is fixed), resolved once here.
-        self._det_events = jnp.zeros(slots, jnp.float32)
-        self._det_alarm_packets = jnp.zeros(slots, jnp.float32)
+        self._det_books: tuple = ((zeros(), zeros())
+                                  if cfg.detection is not None else ())
         self.last_detection = None
+        self._det_alarm_price = 0.0
         if cfg.detection is not None:
             from repro.core.faults import expected_transmissions
             from repro.streaming.detector import detection_packet_split
@@ -505,6 +568,7 @@ class StreamingPCAEngine:
             self._n = n
         elif n != self._n:
             raise ValueError(f"stream n={n} != engine n={self._n}")
+        req.submitted_s = time.perf_counter()
         ok = self.queue.submit(req, priority=req.priority, tenant=req.tenant)
         if not ok and self.telemetry is not None:
             self.telemetry.record_event("rejected", step=self._clock,
@@ -524,8 +588,8 @@ class StreamingPCAEngine:
         """Fill empty slots from the queue front end (priority order,
         oldest-first within a priority, per-tenant quotas respected), then
         reset every admitted slot's device state in ONE batched splice
-        (one scatter per state leaf and per accounting vector, however
-        many slots were admitted).  Returns the number admitted."""
+        (:func:`_admit_splice_fn`, however many slots were admitted).
+        Returns the number admitted."""
         newly: list[int] = []
         load = self._tenant_load()
         for slot in range(self.slots):
@@ -535,6 +599,7 @@ class StreamingPCAEngine:
             if entry is None:
                 break
             req = entry.req
+            req.admitted_s = time.perf_counter()
             self.active[slot] = req
             self.cursor[slot] = req.resume_at
             self.slot_region[slot] = req.region
@@ -552,33 +617,14 @@ class StreamingPCAEngine:
                     resume_at=int(req.resume_at))
         if not newly:
             return 0
-        # fixed-shape masked splice: fresh states for the FULL fleet (one
-        # compile, ever), selected per slot by a (slots,) mask.  A
-        # variable-length at[idx].set would retrace once per distinct
-        # admit count — serving-time compile spikes the sustained-load
-        # benchmark would otherwise report as latency.  jnp.where writes
-        # the identical values, so admission stays bit-identical.
         mask = np.zeros(self.slots, bool)
         mask[newly] = True
-        mj = jnp.asarray(mask)
         if self._fresh_states is None:
             self._fresh_states = jax.vmap(
                 lambda k: stream_init(self.cfg, k))(self._slot_keys)
-        fresh = self._fresh_states
-
-        def splice(full, f):
-            sel = mj.reshape((self.slots,) + (1,) * (f.ndim - 1))
-            return jnp.where(sel, f, full)
-
-        self.states = jax.tree.map(splice, self.states, fresh)
-        if self.cfg.compression is not None:
-            self._comp_max_err = jnp.where(mj, 0.0, self._comp_max_err)
-            self._comp_extras = jnp.where(mj, 0.0, self._comp_extras)
-            self._comp_bits = jnp.where(mj, 0.0, self._comp_bits)
-        if self.cfg.detection is not None:
-            self._det_events = jnp.where(mj, 0.0, self._det_events)
-            self._det_alarm_packets = jnp.where(mj, 0.0,
-                                                self._det_alarm_packets)
+        self.states, self._comp_books, self._det_books = _admit_splice_fn(
+            self.slots)(self.states, self._fresh_states, self._comp_books,
+                        self._det_books, mask)
         return len(newly)
 
     # -- retirement (classify host-side now, pull device scalars later) ------
@@ -594,12 +640,8 @@ class StreamingPCAEngine:
         """Dispatch the retiring slot's device-side summary, BEFORE any
         admission scatter can overwrite the slot.  Async dispatch only;
         nothing is pulled to host here."""
-        comp = ((self._comp_max_err, self._comp_extras, self._comp_bits)
-                if self.cfg.compression is not None else ())
-        det = ((self._det_events, self._det_alarm_packets)
-               if self.cfg.detection is not None else ())
-        return _slot_summary_fn(self.cfg)(self.states, comp, det,
-                                          np.int32(slot))
+        return _slot_summary_fn(self.cfg)(self.states, self._comp_books,
+                                          self._det_books, np.int32(slot))
 
     def _finalize_result(self, slices, reason: str) -> StreamResult:
         """Pull a retiring slot's device scalars and build its
@@ -607,7 +649,8 @@ class StreamingPCAEngine:
         ONE device_get for the whole summary dict: pulling the ~17 fields
         individually pays ~0.3 ms dispatch latency each, which under
         churn would dominate the chunk fold itself."""
-        out = self._pull(jax.device_get(slices), "retire")
+        with self._phases.span("pull"):
+            out = self._pull(jax.device_get(slices), "retire")
         extra: dict = {}
         if self.cfg.compression is not None:
             extra = dict(
@@ -659,6 +702,7 @@ class StreamingPCAEngine:
             pending["revive"] = revive
             if revive is not None:
                 req.resume_at = revive
+                req.submitted_s = time.perf_counter()
                 self.queue.submit(req, priority=req.priority,
                                   tenant=req.tenant, internal=True)
         return pending
@@ -667,6 +711,7 @@ class StreamingPCAEngine:
         req = pending["req"]
         reason = pending["reason"]
         result = self._finalize_result(pending["slices"], reason)
+        req.finished_s = time.perf_counter()
         self.retired_log.append((req, reason))
         if reason == "dead" and pending["revive"] is not None:
             # a continuation will follow: this segment is an early retirement
@@ -684,7 +729,9 @@ class StreamingPCAEngine:
                 reason=reason, tenant=req.tenant,
                 rounds=result.rounds, comm_packets=result.comm_packets,
                 refreshes=result.refreshes,
-                revive=pending["revive"])
+                revive=pending["revive"],
+                queue_wait_s=req.admitted_s - req.submitted_s,
+                service_s=req.finished_s - req.admitted_s)
 
     def _replan(self, n_live: int) -> None:
         """Elastic fleet mesh: one virtual device per live network."""
@@ -734,7 +781,8 @@ class StreamingPCAEngine:
             # retirement pull — and it is on the device_put, never on the
             # chunk fold.
             self._transfer_fences += 1
-            jax.block_until_ready(self._uploads[i])
+            with self._phases.span("fence"):
+                jax.block_until_ready(self._uploads[i])
         buf = self._host_bufs[i]
         rv = np.zeros((self.slots, K), np.float32)
         consumed = np.zeros(self.slots, np.int64)
@@ -776,28 +824,19 @@ class StreamingPCAEngine:
 
     def _accumulate_books(self, metrics, live: list[int]) -> None:
         """Fold the step's stage outputs into the per-slot device
-        accounts.  Idle slots fold zero rounds: mask them out of the
-        books (where, not multiply — robust to any NaN in an idle
-        slot).  All jnp ops — async-dispatchable, no host sync."""
+        accounts (:func:`_books_fn`): one async dispatch, no host sync."""
+        with_c = self.cfg.compression is not None
+        with_m = self.cfg.detection is not None
+        if not (with_c or with_m):
+            return
         lm = np.zeros(self.slots, np.float32)
         lm[live] = 1.0
-        lmj = jnp.asarray(lm)
-        if self.cfg.compression is not None:
-            comp = metrics.compression
-            self.last_compression = comp      # (slots, ...) device arrays
-            self._comp_max_err = jnp.maximum(
-                self._comp_max_err, jnp.where(lmj > 0, comp.max_err, 0.0))
-            self._comp_extras = self._comp_extras + jnp.where(
-                lmj > 0, comp.extra_packets, 0.0)
-            self._comp_bits = self._comp_bits + jnp.where(
-                lmj > 0, comp.bits_on_air, 0.0)
-        if self.cfg.detection is not None:
-            det = metrics.detection
-            self.last_detection = det         # (slots, ...) device arrays
-            alarms = jnp.where(lmj > 0, det.alarms, 0.0)
-            self._det_events = self._det_events + alarms
-            self._det_alarm_packets = (self._det_alarm_packets
-                                       + alarms * self._det_alarm_price)
+        self.last_compression = metrics.compression   # (slots, ...) device
+        self.last_detection = metrics.detection       # arrays, or None
+        self._comp_books, self._det_books = _books_fn(
+            with_c, with_m, self._det_alarm_price)(
+            self._comp_books, self._det_books, metrics.compression,
+            metrics.detection, lm)
 
     # -- main loop ------------------------------------------------------------
     def step(self) -> int:
@@ -816,9 +855,17 @@ class StreamingPCAEngine:
         HealthMonitor iff enough of its sensors were alive over the
         chunk's rounds; slots ruled stalled afterwards are retired dead
         (and re-queued from their revival round, if any).
+
+        Each host phase is a :class:`~repro.serve.telemetry.PhaseTimer`
+        span (``engine.admit``, ``engine.stage`` with ``engine.fence``
+        inside, ``engine.dispatch``, ``engine.bookkeep``, ``engine.retire``
+        with one ``engine.pull`` per result), timed into the step's
+        :class:`StepRecord`.
         """
         t0 = time.perf_counter()
-        admitted = self._admit()
+        phases = self._phases = PhaseTimer()
+        with phases.span("admit"):
+            admitted = self._admit()
         self._clock += 1
         live = [s for s in range(self.slots) if self.active[s]]
         self._replan(len(live))
@@ -829,61 +876,68 @@ class StreamingPCAEngine:
                     step=self._clock, wall_s=time.perf_counter() - t0,
                     stage_s=0.0, overlap_s=0.0, prestaged=False, live=0,
                     rounds=0, queue_depth=len(self.queue),
-                    admitted=admitted, retired=0))
+                    admitted=admitted, retired=0, start_s=t0,
+                    admit_s=phases.get("admit")))
             return 0
         # -- chunk t: consume the prestaged upload, or stage inline --------
         staged, self._staged = self._staged, None
         prestaged = (staged is not None
                      and staged.signature == self._plan_signature())
-        stage_s = 0.0
         if prestaged:
             self._prestage_hits += 1
         else:
             self._prestage_misses += 1
-            t_s = time.perf_counter()
-            staged = self._stage()
-            stage_s = time.perf_counter() - t_s
+            with phases.span("stage"):
+                staged = self._stage()
         # -- dispatch: nothing below blocks on the fold --------------------
-        if staged.masks is not None:
-            self.states, metrics = self._step_fn_masked(
-                self.states, staged.batch, staged.masks, staged.rv)
-        else:
-            self.states, metrics = self._step_fn(
-                self.states, staged.batch, staged.rv)
-        self._accumulate_books(metrics, live)
+        with phases.span("dispatch"):
+            if staged.masks is not None:
+                self.states, metrics = self._step_fn_masked(
+                    self.states, staged.batch, staged.masks, staged.rv)
+            else:
+                self.states, metrics = self._step_fn(
+                    self.states, staged.batch, staged.rv)
+            self._accumulate_books(metrics, live)
         # -- host bookkeeping: heartbeats, cursors, retirement verdicts ----
         pendings: list[dict] = []
-        for s in live:
-            req = self.active[s]
-            c, take = int(staged.start[s]), int(staged.consumed[s])
-            frac = 1.0 if req.liveness is None \
-                else float(req.liveness[c:c + take].mean())
-            if frac >= self.min_alive_fraction:
-                self.health[s].heartbeat(step=self._clock, duration=1.0)
-            self.cursor[s] += take
-            if self.cursor[s] >= req.rounds.shape[0]:
-                pendings.append(self._begin_retire(s, "completed"))
-            elif self.health[s].stalled():
-                pendings.append(self._begin_retire(s, "dead"))
+        with phases.span("bookkeep"):
+            for s in live:
+                req = self.active[s]
+                c, take = int(staged.start[s]), int(staged.consumed[s])
+                frac = 1.0 if req.liveness is None \
+                    else float(req.liveness[c:c + take].mean())
+                if frac >= self.min_alive_fraction:
+                    self.health[s].heartbeat(step=self._clock, duration=1.0)
+                self.cursor[s] += take
+                if self.cursor[s] >= req.rounds.shape[0]:
+                    pendings.append(self._begin_retire(s, "completed"))
+                elif self.health[s].stalled():
+                    pendings.append(self._begin_retire(s, "dead"))
         # -- pipelined prestage: chunk t+1 overlaps the in-flight fold -----
         overlap_s = 0.0
         if self.pipeline:
-            admitted += self._admit()
+            with phases.span("admit"):
+                admitted += self._admit()
             if any(r is not None for r in self.active):
-                t_s = time.perf_counter()
-                self._staged = self._stage()
-                overlap_s = time.perf_counter() - t_s
-                stage_s += overlap_s
+                with phases.span("stage") as prestage:
+                    self._staged = self._stage()
+                overlap_s = prestage.seconds
         # -- retirement results: the loop's only device→host pulls ---------
-        for pending in pendings:
-            self._finish_retire(pending)
+        with phases.span("retire"):
+            for pending in pendings:
+                self._finish_retire(pending)
         if self.telemetry is not None:
             self.telemetry.record_step(StepRecord(
                 step=self._clock, wall_s=time.perf_counter() - t0,
-                stage_s=stage_s, overlap_s=overlap_s, prestaged=prestaged,
-                live=len(live), rounds=int(staged.consumed.sum()),
+                stage_s=phases.get("stage"), overlap_s=overlap_s,
+                prestaged=prestaged, live=len(live),
+                rounds=int(staged.consumed.sum()),
                 queue_depth=len(self.queue), admitted=admitted,
-                retired=len(pendings)))
+                retired=len(pendings), start_s=t0,
+                admit_s=phases.get("admit"), fence_s=phases.get("fence"),
+                dispatch_s=phases.get("dispatch"),
+                bookkeep_s=phases.get("bookkeep"),
+                retire_s=phases.get("retire")))
         return len(live)
 
     def run_until_done(self, max_steps: int = 100_000) -> None:

@@ -304,6 +304,11 @@ def chunk_stream_step(cfg: StreamConfig, state: StreamState,
     round's scores between projection and reconstruction) and (K, n, p)
     per-reading dropout masks (their pairwise counts need a second kernel
     pass anyway) keep the split path.
+
+    The fold (with the fused kernel), the stage twin and the books run
+    under the named scopes ``pca.fold``, ``pca.twin`` and ``pca.books``
+    (the scheduler adds ``pca.probe`` and ``pca.refresh``), which a device
+    trace carries in each op's ``op_name`` path.
     """
     K, n, p = x_chunk.shape
     if masks is not None:
@@ -321,65 +326,66 @@ def chunk_stream_step(cfg: StreamConfig, state: StreamState,
         rv = jnp.asarray(round_valid, jnp.float32)
         live = jnp.sum(rv)
         live_i = live.astype(jnp.int32)
-    if masks is None:
-        churn = jnp.zeros((), bool)
-        alive = state.alive
-    else:
-        churn = jnp.zeros((), bool)
-        alive = state.alive
-        for t in range(K):                  # static unroll, K is small
-            changed = jnp.any(masks[t] != alive)
-            if rv is None:
-                churn = churn | changed
-                alive = masks[t]
-            else:
-                v_t = rv[t] > 0
-                churn = churn | (v_t & changed)
-                alive = jnp.where(v_t, masks[t], alive)
-    # the stages already vectorize over epochs: they see the (K·n, p)
-    # chunk view, with pad/idle rounds masked out (a padded epoch is a
-    # dead epoch: no record, no flag)
-    x_view = x_chunk.reshape(K * n, p)
-    mask_view = None
-    if has_stage and (masks is not None or rv is not None):
-        m3 = jnp.ones((K, n, p), x_view.dtype) if masks is None \
-            else jnp.broadcast_to(masks[:, None, :], (K, n, p))
-        if rv is not None:
-            m3 = m3 * rv[:, None, None].astype(m3.dtype)
-        mask_view = m3.reshape(K * n, p)
+    with jax.named_scope("pca.fold"):
+        if masks is None:
+            churn = jnp.zeros((), bool)
+            alive = state.alive
+        else:
+            churn = jnp.zeros((), bool)
+            alive = state.alive
+            for t in range(K):                  # static unroll, K is small
+                changed = jnp.any(masks[t] != alive)
+                if rv is None:
+                    churn = churn | changed
+                    alive = masks[t]
+                else:
+                    v_t = rv[t] > 0
+                    churn = churn | (v_t & changed)
+                    alive = jnp.where(v_t, masks[t], alive)
+        # the stages already vectorize over epochs: they see the (K·n, p)
+        # chunk view, with pad/idle rounds masked out (a padded epoch is a
+        # dead epoch: no record, no flag)
+        x_view = x_chunk.reshape(K * n, p)
+        mask_view = None
+        if has_stage and (masks is not None or rv is not None):
+            m3 = jnp.ones((K, n, p), x_view.dtype) if masks is None \
+                else jnp.broadcast_to(masks[:, None, :], (K, n, p))
+            if rv is not None:
+                m3 = m3 * rv[:, None, None].astype(m3.dtype)
+            mask_view = m3.reshape(K * n, p)
 
-    z = x_hat = flags = t2 = spe = None
-    if use_fused:
-        with_c = cfg.compression is not None
-        with_m = cfg.detection is not None
-        # analytic half of the fold first: the kernel needs the POST-fold
-        # mean estimate as a stage operand, and s/t_band never touch a
-        # kernel (online_apply_chunk shares the arithmetic, so the split
-        # path produces the same bits)
-        w, beta_eff, delta_s, delta_tb = online_chunk_stats(
-            state.cov, x_chunk, forgetting=cfg.forgetting, masks=masks,
-            round_valid=round_valid)
-        s_new = beta_eff * state.cov.s + delta_s
-        t_i_new = (beta_eff * state.cov.t_band + delta_tb)[cfg.halfwidth]
-        mean_est = s_new / jnp.maximum(t_i_new, 1.0)
-        il = inv_lambda(state.sched.lam, cfg.detection) if with_m \
-            else jnp.ones((cfg.q,), jnp.float32)
-        eps = cfg.compression.epsilon if with_c else 0.0
-        # ONE kernel launch: band fold + stages against the pre-decision
-        # basis (== post-decision whenever the scheduler does not fire)
-        band_delta, z, x_hat, flags, t2, spe = ops.fused_stream_update(
-            x_view, jnp.repeat(w, n), state.sched.W, mean_est, il,
-            halfwidth=cfg.halfwidth, epsilon=eps, with_compress=with_c,
-            with_monitor=with_m, mask=mask_view, precision=cfg.precision,
-            interpret=cfg.interpret)
-        cov = online_apply_chunk(state.cov, band_delta, w, beta_eff,
-                                 delta_s, delta_tb, n)
-    else:
-        cov = online_update_chunk(state.cov, x_chunk,
-                                  forgetting=cfg.forgetting, masks=masks,
-                                  round_valid=round_valid,
-                                  interpret=cfg.interpret)
-        mean_est = cov.s / jnp.maximum(cov.t_i, 1.0)
+        z = x_hat = flags = t2 = spe = None
+        if use_fused:
+            with_c = cfg.compression is not None
+            with_m = cfg.detection is not None
+            # analytic half of the fold first: the kernel needs the POST-fold
+            # mean estimate as a stage operand, and s/t_band never touch a
+            # kernel (online_apply_chunk shares the arithmetic, so the split
+            # path produces the same bits)
+            w, beta_eff, delta_s, delta_tb = online_chunk_stats(
+                state.cov, x_chunk, forgetting=cfg.forgetting, masks=masks,
+                round_valid=round_valid)
+            s_new = beta_eff * state.cov.s + delta_s
+            t_i_new = (beta_eff * state.cov.t_band + delta_tb)[cfg.halfwidth]
+            mean_est = s_new / jnp.maximum(t_i_new, 1.0)
+            il = inv_lambda(state.sched.lam, cfg.detection) if with_m \
+                else jnp.ones((cfg.q,), jnp.float32)
+            eps = cfg.compression.epsilon if with_c else 0.0
+            # ONE kernel launch: band fold + stages against the pre-decision
+            # basis (== post-decision whenever the scheduler does not fire)
+            band_delta, z, x_hat, flags, t2, spe = ops.fused_stream_update(
+                x_view, jnp.repeat(w, n), state.sched.W, mean_est, il,
+                halfwidth=cfg.halfwidth, epsilon=eps, with_compress=with_c,
+                with_monitor=with_m, mask=mask_view, precision=cfg.precision,
+                interpret=cfg.interpret)
+            cov = online_apply_chunk(state.cov, band_delta, w, beta_eff,
+                                     delta_s, delta_tb, n)
+        else:
+            cov = online_update_chunk(state.cov, x_chunk,
+                                      forgetting=cfg.forgetting, masks=masks,
+                                      round_valid=round_valid,
+                                      interpret=cfg.interpret)
+            mean_est = cov.s / jnp.maximum(cov.t_i, 1.0)
     # one decision at the boundary, indexed at the LAST folded round (the
     # same warmup arithmetic the per-round path would apply at that round)
     sched, rho, fired = cfg.scheduler().step(state.sched, cov,
@@ -406,6 +412,7 @@ def chunk_stream_step(cfg: StreamConfig, state: StreamState,
                 out += [t2_, spe_]
             return tuple(out)
 
+        @jax.named_scope("pca.twin")
         def _recompute(_):
             il2 = inv_lambda(sched.lam, cfg.detection) if with_m else il
             return _pack(*ops.fused_stream_stages_blocked(
@@ -423,48 +430,52 @@ def chunk_stream_step(cfg: StreamConfig, state: StreamState,
             k_out += 2
         if with_m:
             t2, spe = staged[k_out], staged[k_out + 1]
-    compression = None
-    if cfg.compression is not None:
-        if use_fused:
-            mask2d = mask_view if mask_view is not None \
-                else jnp.ones((K * n, p), jnp.float32)
-            compression = compression_books(
-                jnp.asarray(x_view, jnp.float32), z, x_hat, flags, mask2d,
-                cfg.compression, cfg.q, cfg.c_max)
-        else:
-            compression = compress_round(
-                sched.W, mean_est, x_view, cfg.compression, cfg.c_max,
-                mask=mask_view, interpret=cfg.interpret)
-        flagfree = compression_round_cost(cfg.q, cfg.c_max, cfg.compression)
-        bill = (flagfree * live + compression.extra_packets) * factor
-        sched = sched._replace(comm_packets=sched.comm_packets + bill)
-        # compress_round's fixed A/F record (and its bits) covers ONE
-        # epoch round; this metrics row covers the chunk's live rounds —
-        # scale the per-round constants so the books a consumer sums from
-        # metrics (the engine's bits_on_air account) stay per-epoch exact
-        # like comm_packets above (static no-op at K=1)
-        if not (isinstance(live, int) and live == 1):
-            a_pk, f_pk = epoch_packet_split(cfg.q, cfg.c_max,
-                                            cfg.compression)
-            compression = compression._replace(
-                score_packets=compression.score_packets * live,
-                feedback_packets=compression.feedback_packets * live,
-                bits_on_air=compression.bits_on_air
-                + (live - 1) * (a_pk + f_pk) * cfg.compression.word_bits)
-    det_state, detection = state.det, None
-    if cfg.detection is not None:
-        if use_fused:
-            det_state, detection = detect_apply(
-                t2, spe, row_liveness(mask_view, K * n, t2.dtype), cfg.q,
-                state.det, cfg.detection, refreshed=fired)
-        else:
-            det_state, detection = detect_round(
-                sched.W, mean_est, sched.lam, x_view, state.det,
-                cfg.detection, refreshed=fired, mask=mask_view,
-                interpret=cfg.interpret)
-        flagfree, per_alarm = detection_packet_split(cfg.q, cfg.c_max)
-        bill = (flagfree * live + detection.alarms * per_alarm) * factor
-        sched = sched._replace(comm_packets=sched.comm_packets + bill)
+    # the compression/detection books and their bills (on the split path
+    # the stage passes themselves run here too)
+    with jax.named_scope("pca.books"):
+        compression = None
+        if cfg.compression is not None:
+            if use_fused:
+                mask2d = mask_view if mask_view is not None \
+                    else jnp.ones((K * n, p), jnp.float32)
+                compression = compression_books(
+                    jnp.asarray(x_view, jnp.float32), z, x_hat, flags, mask2d,
+                    cfg.compression, cfg.q, cfg.c_max)
+            else:
+                compression = compress_round(
+                    sched.W, mean_est, x_view, cfg.compression, cfg.c_max,
+                    mask=mask_view, interpret=cfg.interpret)
+            flagfree = compression_round_cost(cfg.q, cfg.c_max,
+                                              cfg.compression)
+            bill = (flagfree * live + compression.extra_packets) * factor
+            sched = sched._replace(comm_packets=sched.comm_packets + bill)
+            # compress_round's fixed A/F record (and its bits) covers ONE
+            # epoch round; this metrics row covers the chunk's live rounds —
+            # scale the per-round constants so the books a consumer sums from
+            # metrics (the engine's bits_on_air account) stay per-epoch exact
+            # like comm_packets above (static no-op at K=1)
+            if not (isinstance(live, int) and live == 1):
+                a_pk, f_pk = epoch_packet_split(cfg.q, cfg.c_max,
+                                                cfg.compression)
+                compression = compression._replace(
+                    score_packets=compression.score_packets * live,
+                    feedback_packets=compression.feedback_packets * live,
+                    bits_on_air=compression.bits_on_air
+                    + (live - 1) * (a_pk + f_pk) * cfg.compression.word_bits)
+        det_state, detection = state.det, None
+        if cfg.detection is not None:
+            if use_fused:
+                det_state, detection = detect_apply(
+                    t2, spe, row_liveness(mask_view, K * n, t2.dtype), cfg.q,
+                    state.det, cfg.detection, refreshed=fired)
+            else:
+                det_state, detection = detect_round(
+                    sched.W, mean_est, sched.lam, x_view, state.det,
+                    cfg.detection, refreshed=fired, mask=mask_view,
+                    interpret=cfg.interpret)
+            flagfree, per_alarm = detection_packet_split(cfg.q, cfg.c_max)
+            bill = (flagfree * live + detection.alarms * per_alarm) * factor
+            sched = sched._replace(comm_packets=sched.comm_packets + bill)
     new = StreamState(cov=cov, sched=sched, rounds=state.rounds + live_i,
                       alive=alive, det=det_state)
     metrics = RoundMetrics(rho=rho, did_refresh=fired,

@@ -18,6 +18,8 @@ report accuracy-vs-communication exactly like Fig. 9/14.
 Everything is branch-free jittable: the refresh is a ``lax.cond`` whose
 batched (vmap) lowering evaluates both branches and selects per network —
 the cost model, not XLA, is the source of truth for what a WSN would pay.
+The drift probe and the refresh run under the named scopes ``pca.probe``
+and ``pca.refresh``, so a device trace gives each its own time.
 """
 
 from __future__ import annotations
@@ -172,15 +174,17 @@ class RecomputeScheduler:
         estimate to catch up over the forgetting window.
         """
         p = state.W.shape[0]
-        band_est = online_estimate(cov_state)
-        total_var = online_total_variance(cov_state)
-        rho = retained_fraction(band_est, state.W, total_var)
+        with jax.named_scope("pca.probe"):
+            band_est = online_estimate(cov_state)
+            total_var = online_total_variance(cov_state)
+            rho = retained_fraction(band_est, state.W, total_var)
 
         past_warmup = round_index >= self.warmup_rounds
         never_fit = state.refreshes == 0
         drifted = (state.rho_ref - rho) > self.drift_threshold
         trigger = past_warmup & (never_fit | drifted | jnp.asarray(churn))
 
+        @jax.named_scope("pca.refresh")
         def do_refresh(_):
             W_new, lam_new = ortho_refresh_evals(band_est, state.W,
                                                  self.refresh_iters)
