@@ -10,11 +10,12 @@ batch, host-side slot management, jitted steps*:
 * :class:`StreamingPCAEngine` — the sensor path (DESIGN.md Sec. 8.4/12/17):
   each slot holds one live sensor network; every engine step stages the
   next K-round chunk per slot and folds it through the jitted batched chunk
-  step (:func:`repro.streaming.driver.engine_chunk_step_fn` — the vmapped
-  :func:`~repro.streaming.driver.chunk_stream_step` with the fleet state
-  donated so XLA updates it in place), drift-triggered basis refreshes
-  happen at chunk boundaries inside the step, and exhausted streams retire
-  with their final basis + Table-1 communication bill.
+  step (:func:`repro.streaming.driver.engine_chunk_step_fn` — the fleet
+  form of :func:`~repro.streaming.driver.chunk_stream_step` with the fleet
+  state donated so XLA updates it in place), drift-triggered basis
+  refreshes happen at chunk boundaries inside the step, computed only for
+  the slots whose trigger fired, and exhausted streams retire with their
+  final basis + Table-1 communication bill.
   ``StreamConfig.fused``/``precision`` flow straight through the vmapped
   step: with stages configured each slot's chunk body is the one-launch
   mega-kernel (DESIGN.md Sec. 14), and ``precision="bf16"`` stages the
@@ -317,7 +318,7 @@ def _slot_summary_fn(cfg: StreamConfig):
     from repro.streaming.scheduler import retained_fraction
 
     @jax.named_scope("pca.retire")
-    def summarize(states, comp, det, i):
+    def summarize(states, comp, det, refresh, i):
         st = jax.tree.map(lambda a: a[i], states)
         out = dict(
             W=st.sched.W,
@@ -325,7 +326,8 @@ def _slot_summary_fn(cfg: StreamConfig):
                                   online_total_variance(st.cov)),
             refreshes=st.sched.refreshes,
             comm_packets=st.sched.comm_packets,
-            rounds=st.rounds)
+            rounds=st.rounds,
+            refresh_fired=refresh[0], refresh_computed=refresh[1])
         out["lam"], out["total"] = region_energies(st)
         if cfg.compression is not None:
             out.update(comp_max=comp[0][i], comp_extra=comp[1][i],
@@ -367,12 +369,20 @@ def _admit_splice_fn(slots: int):
 @functools.lru_cache(maxsize=None)
 def _books_fn(with_compression: bool, with_detection: bool,
               alarm_price: float):
-    """Fold one step's stage outputs into the per-slot device books, as
-    one jitted program.  Idle slots fold zero rounds: they are masked out
-    (where, not multiply — robust to any NaN in an idle slot)."""
+    """Fold one step's stage outputs into the per-slot device books, and
+    its refresh into the fleet's two refresh counts (slots that fired,
+    slots the bucketed refresh computed), as one jitted program.  Idle
+    slots fold zero rounds: they are masked out of the stage books (where,
+    not multiply — robust to any NaN in an idle slot)."""
 
     @jax.named_scope("pca.books")
-    def books(comp_books, det_books, comp, det, live):
+    def books(comp_books, det_books, refresh_books, comp, det, fired,
+              computed, live):
+        if computed is None:             # a vmapped select refreshes all
+            computed = fired.shape[0]
+        refresh_books = (
+            refresh_books[0] + jnp.sum(fired, dtype=jnp.int32),
+            refresh_books[1] + computed)
         on = live > 0
         if with_compression:
             max_err, extras, bits = comp_books
@@ -385,7 +395,7 @@ def _books_fn(with_compression: bool, with_detection: bool,
             alarms = jnp.where(on, det.alarms, 0.0)
             det_books = (events + alarms,
                          alarm_packets + alarms * alarm_price)
-        return comp_books, det_books
+        return comp_books, det_books, refresh_books
 
     return jax.jit(books)
 
@@ -423,7 +433,7 @@ class StreamingPCAEngine:
     chunk: rounds folded per engine step (K).  Each step stages every
         slot's next K rounds device-side in ONE upload, folds them through
         the fused chunk kernel, and evaluates ONE scheduler decision per
-        slot — the per-dispatch overhead (launches, refresh selects,
+        slot — the per-dispatch overhead (launches, refresh decisions,
         host→device transfers, slot bookkeeping) is amortized over K
         measurement epochs while the Table-1 bill stays per-epoch exact.
         Admission and retirement happen at chunk boundaries; a stream
@@ -530,6 +540,13 @@ class StreamingPCAEngine:
         self._det_books: tuple = ((zeros(), zeros())
                                   if cfg.detection is not None else ())
         self.last_detection = None
+        # fleet-wide refresh counts since the engine started, on device
+        # like the books: slots whose trigger fired, and slots the bucketed
+        # refresh computed (fired / computed is the bucket fill).  Pulled
+        # with each retirement's summary into refresh_slots.
+        self._refresh_books = (jnp.zeros((), jnp.int32),
+                               jnp.zeros((), jnp.int32))
+        self.refresh_slots = {"fired": 0, "computed": 0}
         self._det_alarm_price = 0.0
         if cfg.detection is not None:
             from repro.core.faults import expected_transmissions
@@ -641,7 +658,8 @@ class StreamingPCAEngine:
         admission scatter can overwrite the slot.  Async dispatch only;
         nothing is pulled to host here."""
         return _slot_summary_fn(self.cfg)(self.states, self._comp_books,
-                                          self._det_books, np.int32(slot))
+                                          self._det_books,
+                                          self._refresh_books, np.int32(slot))
 
     def _finalize_result(self, slices, reason: str) -> StreamResult:
         """Pull a retiring slot's device scalars and build its
@@ -651,6 +669,8 @@ class StreamingPCAEngine:
         churn would dominate the chunk fold itself."""
         with self._phases.span("pull"):
             out = self._pull(jax.device_get(slices), "retire")
+        self.refresh_slots = {"fired": int(out["refresh_fired"]),
+                              "computed": int(out["refresh_computed"])}
         extra: dict = {}
         if self.cfg.compression is not None:
             extra = dict(
@@ -724,6 +744,8 @@ class StreamingPCAEngine:
             req.done = True
             self.region_results[pending["region"]] = result
         if self.telemetry is not None:
+            self.telemetry.record_refresh(self.refresh_slots["fired"],
+                                          self.refresh_slots["computed"])
             self.telemetry.record_event(
                 "retired", step=self._clock, slot=pending["slot"],
                 reason=reason, tenant=req.tenant,
@@ -823,20 +845,20 @@ class StreamingPCAEngine:
                             signature=self._plan_signature())
 
     def _accumulate_books(self, metrics, live: list[int]) -> None:
-        """Fold the step's stage outputs into the per-slot device
-        accounts (:func:`_books_fn`): one async dispatch, no host sync."""
+        """Fold the step's stage outputs and refresh counts into the
+        device accounts (:func:`_books_fn`): one async dispatch, no host
+        sync."""
         with_c = self.cfg.compression is not None
         with_m = self.cfg.detection is not None
-        if not (with_c or with_m):
-            return
         lm = np.zeros(self.slots, np.float32)
         lm[live] = 1.0
         self.last_compression = metrics.compression   # (slots, ...) device
         self.last_detection = metrics.detection       # arrays, or None
-        self._comp_books, self._det_books = _books_fn(
+        self._comp_books, self._det_books, self._refresh_books = _books_fn(
             with_c, with_m, self._det_alarm_price)(
-            self._comp_books, self._det_books, metrics.compression,
-            metrics.detection, lm)
+            self._comp_books, self._det_books, self._refresh_books,
+            metrics.compression, metrics.detection, metrics.did_refresh,
+            metrics.refresh_slots, lm)
 
     # -- main loop ------------------------------------------------------------
     def step(self) -> int:

@@ -18,7 +18,9 @@ values rather than causing its own transfer.
 
 ``summary()`` folds the ring into the serving headline numbers: p50/p99
 step latency, mean staged-vs-compute overlap fraction, prestage hit rate,
-throughput, admission/retirement totals.
+throughput, admission/retirement totals, and the refresh's bucket fill:
+the slots whose trigger fired over the slots the bucketed refresh computed,
+as of the last retirement (the engine pulls its device counts there).
 
 :class:`PhaseTimer` times the phases of one engine step (admit, stage,
 dispatch, bookkeeping, retirement) once each: every phase is a
@@ -158,6 +160,9 @@ class TelemetryRecorder:
         self.total_wall_s = 0.0
         self._first_start: float | None = None
         self._last_end = 0.0
+        self.refresh_fired_slots = 0
+        self.refresh_computed_slots = 0
+        self._refresh_seen = (0, 0)
         self._sink: IO[str] | None = (
             open(jsonl_path, "a") if jsonl_path else None)
 
@@ -184,6 +189,14 @@ class TelemetryRecorder:
         if self._sink is not None:
             json.dump(rec, self._sink)
             self._sink.write("\n")
+
+    def record_refresh(self, fired: int, computed: int) -> None:
+        """The engine's lifetime counts of slots that fired and slots the
+        bucketed refresh computed, as pulled at a retirement; the totals
+        gain what the counts gained since the last call."""
+        self.refresh_fired_slots += fired - self._refresh_seen[0]
+        self.refresh_computed_slots += computed - self._refresh_seen[1]
+        self._refresh_seen = (fired, computed)
 
     # -- summaries -----------------------------------------------------------
     def step_latency_percentiles(self, qs=(50.0, 99.0)) -> dict[str, float]:
@@ -235,6 +248,11 @@ class TelemetryRecorder:
             "p99_step_s": pct["p99"],
             "overlap_fraction": self.mean_overlap_fraction(),
             "prestage_hit_rate": self.prestage_hit_rate(),
+            "refresh_fired_slots": self.refresh_fired_slots,
+            "refresh_computed_slots": self.refresh_computed_slots,
+            "refresh_bucket_fill": (
+                self.refresh_fired_slots / self.refresh_computed_slots
+                if self.refresh_computed_slots else 0.0),
         }
 
     def reset(self) -> None:
@@ -250,6 +268,8 @@ class TelemetryRecorder:
         self.total_wall_s = 0.0
         self._first_start = None
         self._last_end = 0.0
+        self.refresh_fired_slots = 0
+        self.refresh_computed_slots = 0
 
     def close(self) -> None:
         if self._sink is not None:

@@ -14,9 +14,13 @@ Layering (DESIGN.md Sec. 8.3):
   ``probe_every=1`` is bit-identical to the per-round driver.
 * :func:`batched_stream_run` — ``jax.vmap`` of the run over a leading
   networks axis: hundreds of independent sensor networks stream concurrently
-  in one program — the serving shape.  The scheduler's ``lax.cond`` lowers to
-  a select, so each round costs one (masked) refresh for the whole batch
-  while the *booked* WSN cost stays per-network exact.
+  in one program.  The scheduler's ``lax.cond`` lowers to a select, so each
+  round costs one (masked) refresh for the whole batch while the *booked*
+  WSN cost stays per-network exact.
+* :func:`engine_chunk_step_fn` — the serving engine's fleet step: the chunk
+  body split at the decision, with the refresh computed only for the slots
+  that fired (:meth:`repro.streaming.scheduler.RecomputeScheduler.
+  refresh_fired`) instead of a select over the whole fleet.
 * :func:`sharded_stream_run` — the batched run inside ``shard_map`` with the
   networks axis split over the mesh data axis
   (:func:`repro.distributed.sharding.network_axis_spec`); per-network state
@@ -135,6 +139,8 @@ class RoundMetrics(NamedTuple):
     comm_packets: jnp.ndarray       # cumulative communication (packets)
     compression: RoundCompression | None = None  # ε-supervised output
     detection: RoundDetection | None = None      # T²/SPE monitoring output
+    refresh_slots: jnp.ndarray | None = None     # engine fleet step only:
+    #                       () int32 slots its bucketed refresh computed
 
 
 def _metrics_template(cfg: "StreamConfig") -> RoundMetrics:
@@ -254,78 +260,55 @@ def stream_step(cfg: StreamConfig, state: StreamState, x_round: jnp.ndarray,
     return new, metrics
 
 
-@_fp32_matmuls
-def chunk_stream_step(cfg: StreamConfig, state: StreamState,
-                      x_chunk: jnp.ndarray,
-                      masks: jnp.ndarray | None = None,
-                      round_valid: jnp.ndarray | None = None,
-                      ) -> tuple[StreamState, RoundMetrics]:
-    """K rounds for one network in ONE dispatch: fused covariance fold of
-    the whole (K, n, p) chunk (:func:`online_update_chunk` — one kernel
-    launch, one HBM band writeback), then ONE scheduler decision at the
-    chunk boundary, then one compression/detection pass over the chunk's
-    (K·n, p) epoch view.
+class _Folded(NamedTuple):
+    """What the pre-decision half of a chunk (:func:`_chunk_fold`) hands
+    the post-decision half (:func:`_chunk_finish`)."""
 
-    The Table-1 bill stays per-EPOCH exact: the chunk books K per-round
-    drift records (and K flag-free compression/monitoring epochs) even
-    though only one decision is evaluated — the WSN would still aggregate
-    every epoch; only the eigenvector-phase *decisions* are amortized.
+    cov: OnlineCovariance
+    churn: jnp.ndarray              # () bool: liveness changed in the chunk
+    alive: jnp.ndarray              # (p,) liveness after the chunk
+    mean_est: jnp.ndarray           # (p,) post-fold mean estimate
+    mask_view: jnp.ndarray | None   # (K·n, p) epoch liveness of the stages
+    stages: tuple                   # fused path: the kernel's (z, x_hat,
+    #                                 flags, t2, spe) against the old basis
 
-    ``masks`` is the chunk's (K, p) per-round liveness schedule; any
-    liveness change across the chunk (vs. the state's last-seen liveness)
-    raises the scheduler's churn trigger at the boundary.  ``round_valid``
-    (K,) flags which rounds are real — 0 rounds (stream tail padding, or
-    an engine slot whose stream ends mid-chunk) contribute nothing to the
-    fold, the stages, the books, or the round counter.
 
-    At K=1 this is bit-identical to :func:`stream_step` (the chunk kernel
-    with weight 1 is the per-round kernel, and every booking term reduces
-    to the per-round expression exactly) — the differential guarantee
-    behind ``chunked_stream_run(..., probe_every=1)``.
+def _live_rounds(K: int, round_valid):
+    """``(rv, live, live_i)``: the chunk's round validity and its count of
+    live rounds (the static K when every round is real)."""
+    if round_valid is None:
+        return None, K, K
+    rv = jnp.asarray(round_valid, jnp.float32)
+    live = jnp.sum(rv)
+    return rv, live, live.astype(jnp.int32)
 
-    With a compression and/or detection stage configured, the chunk body
-    takes the FUSED path by default (``cfg.fused``; DESIGN.md Sec. 14):
-    one mega-kernel (:func:`repro.kernels.ops.fused_stream_update`) loads
-    each chunk tile into VMEM once and emits the band delta AND the stage
-    outputs — 1 ``pallas_call`` per chunk body instead of 3.  The stages
-    are speculated against the pre-decision basis (bit-identical to the
-    post-decision basis whenever the scheduler does not fire — the
-    refresh is a select); on the refresh rounds a pure-jnp twin
-    (:func:`repro.kernels.ops.fused_stream_stages_blocked`, the kernel's
-    stage arithmetic to fp32 rounding) recomputes them against the rotated
-    basis under ``lax.cond``.  At fp32 the fused path is bit-identical to
-    the split path; ``cfg.precision="bf16"`` halves the kernel's tile
-    traffic (fp32 accumulation) at tolerance-level divergence — note the
-    ε flag decision then happens in tile precision, so the fp32-measured
-    sink error can overshoot ε by the bf16 rounding (~1e-3 relative);
-    deployments that need the bound exact in fp32 keep the default
-    precision.  Quantized
-    compression (``score_bits > 0`` — the quantizer needs the whole
-    round's scores between projection and reconstruction) and (K, n, p)
-    per-reading dropout masks (their pairwise counts need a second kernel
-    pass anyway) keep the split path.
 
-    The fold (with the fused kernel), the stage twin and the books run
-    under the named scopes ``pca.fold``, ``pca.twin`` and ``pca.books``
-    (the scheduler adds ``pca.probe`` and ``pca.refresh``), which a device
-    trace carries in each op's ``op_name`` path.
-    """
+def _uses_fused(cfg: StreamConfig, masks) -> bool:
+    return (cfg.fused
+            and (cfg.compression is not None or cfg.detection is not None)
+            and (cfg.compression is None or cfg.compression.score_bits == 0)
+            and (masks is None or masks.ndim == 2))
+
+
+def _decision_round(state: StreamState, x_chunk: jnp.ndarray, round_valid):
+    """The round a chunk's decision is indexed at: its LAST folded round
+    (the same warmup arithmetic the per-round path would apply there)."""
+    live_i = _live_rounds(x_chunk.shape[0], round_valid)[2]
+    return state.rounds + (live_i - 1)
+
+
+def _chunk_fold(cfg: StreamConfig, state: StreamState, x_chunk: jnp.ndarray,
+                masks: jnp.ndarray | None, round_valid: jnp.ndarray | None,
+                ) -> _Folded:
+    """The pre-decision half of :func:`chunk_stream_step`: the covariance
+    fold (with the fused kernel's speculative stages), the analytic half
+    and the churn flag."""
     K, n, p = x_chunk.shape
     if masks is not None:
         masks = jnp.asarray(masks, state.alive.dtype)
     has_stage = cfg.compression is not None or cfg.detection is not None
-    use_fused = (cfg.fused and has_stage
-                 and (cfg.compression is None
-                      or cfg.compression.score_bits == 0)
-                 and (masks is None or masks.ndim == 2))
-    if round_valid is None:
-        rv = None
-        live = K                            # static: folds into constants
-        live_i = K
-    else:
-        rv = jnp.asarray(round_valid, jnp.float32)
-        live = jnp.sum(rv)
-        live_i = live.astype(jnp.int32)
+    use_fused = _uses_fused(cfg, masks)
+    rv = _live_rounds(K, round_valid)[0]
     with jax.named_scope("pca.fold"):
         if masks is None:
             churn = jnp.zeros((), bool)
@@ -354,7 +337,7 @@ def chunk_stream_step(cfg: StreamConfig, state: StreamState,
                 m3 = m3 * rv[:, None, None].astype(m3.dtype)
             mask_view = m3.reshape(K * n, p)
 
-        z = x_hat = flags = t2 = spe = None
+        stages = ()
         if use_fused:
             with_c = cfg.compression is not None
             with_m = cfg.detection is not None
@@ -373,11 +356,12 @@ def chunk_stream_step(cfg: StreamConfig, state: StreamState,
             eps = cfg.compression.epsilon if with_c else 0.0
             # ONE kernel launch: band fold + stages against the pre-decision
             # basis (== post-decision whenever the scheduler does not fire)
-            band_delta, z, x_hat, flags, t2, spe = ops.fused_stream_update(
+            band_delta, *stages = ops.fused_stream_update(
                 x_view, jnp.repeat(w, n), state.sched.W, mean_est, il,
                 halfwidth=cfg.halfwidth, epsilon=eps, with_compress=with_c,
                 with_monitor=with_m, mask=mask_view, precision=cfg.precision,
                 interpret=cfg.interpret)
+            stages = tuple(stages)
             cov = online_apply_chunk(state.cov, band_delta, w, beta_eff,
                                      delta_s, delta_tb, n)
         else:
@@ -386,11 +370,26 @@ def chunk_stream_step(cfg: StreamConfig, state: StreamState,
                                       round_valid=round_valid,
                                       interpret=cfg.interpret)
             mean_est = cov.s / jnp.maximum(cov.t_i, 1.0)
-    # one decision at the boundary, indexed at the LAST folded round (the
-    # same warmup arithmetic the per-round path would apply at that round)
-    sched, rho, fired = cfg.scheduler().step(state.sched, cov,
-                                             state.rounds + (live_i - 1),
-                                             churn=churn)
+    return _Folded(cov=cov, churn=churn, alive=alive, mean_est=mean_est,
+                   mask_view=mask_view, stages=stages)
+
+
+def _chunk_finish(cfg: StreamConfig, state: StreamState,
+                  x_chunk: jnp.ndarray, masks: jnp.ndarray | None,
+                  round_valid: jnp.ndarray | None, folded: _Folded,
+                  sched: SchedulerState, rho: jnp.ndarray,
+                  fired: jnp.ndarray) -> tuple[StreamState, RoundMetrics]:
+    """The post-decision half of :func:`chunk_stream_step`, given the
+    decision (``sched`` with the refresh, if it fired, and one round
+    booked): the remaining rounds' records, the stage twin, the books and
+    the metrics."""
+    K, n, p = x_chunk.shape
+    if masks is not None:
+        masks = jnp.asarray(masks, state.alive.dtype)
+    use_fused = _uses_fused(cfg, masks)
+    live, live_i = _live_rounds(K, round_valid)[1:]
+    x_view = x_chunk.reshape(K * n, p)
+    mean_est, mask_view = folded.mean_est, folded.mask_view
     # step() booked one per-round record; book the chunk's remaining live
     # rounds (static no-op at K=1)
     extra = live - 1
@@ -399,7 +398,12 @@ def chunk_stream_step(cfg: StreamConfig, state: StreamState,
             comm_packets=sched.comm_packets
             + extra * cfg.scheduler().round_cost())
     factor = expected_transmissions(cfg.link_loss, cfg.max_retries)
+    z = x_hat = flags = t2 = spe = None
     if use_fused:
+        with_c = cfg.compression is not None
+        with_m = cfg.detection is not None
+        eps = cfg.compression.epsilon if with_c else 0.0
+
         # the decision fired: the stages must reflect the rotated basis
         # (and its λ̂) — the pure-jnp twin recomputes them bit-identically
         # to what the kernel would produce, without a second pallas_call
@@ -414,14 +418,15 @@ def chunk_stream_step(cfg: StreamConfig, state: StreamState,
 
         @jax.named_scope("pca.twin")
         def _recompute(_):
-            il2 = inv_lambda(sched.lam, cfg.detection) if with_m else il
+            il2 = inv_lambda(sched.lam, cfg.detection) if with_m \
+                else jnp.ones((cfg.q,), jnp.float32)
             return _pack(*ops.fused_stream_stages_blocked(
                 x_view, sched.W, mean_est, il2, epsilon=eps,
                 with_compress=with_c, with_monitor=with_m, mask=mask_view,
                 precision=cfg.precision))
 
         staged = jax.lax.cond(fired, _recompute,
-                              lambda _: _pack(z, x_hat, flags, t2, spe),
+                              lambda _: _pack(*folded.stages),
                               operand=None)
         z = staged[0]
         k_out = 1
@@ -476,8 +481,9 @@ def chunk_stream_step(cfg: StreamConfig, state: StreamState,
             flagfree, per_alarm = detection_packet_split(cfg.q, cfg.c_max)
             bill = (flagfree * live + detection.alarms * per_alarm) * factor
             sched = sched._replace(comm_packets=sched.comm_packets + bill)
-    new = StreamState(cov=cov, sched=sched, rounds=state.rounds + live_i,
-                      alive=alive, det=det_state)
+    new = StreamState(cov=folded.cov, sched=sched,
+                      rounds=state.rounds + live_i, alive=folded.alive,
+                      det=det_state)
     metrics = RoundMetrics(rho=rho, did_refresh=fired,
                            refreshes=sched.refreshes,
                            comm_packets=sched.comm_packets,
@@ -486,10 +492,92 @@ def chunk_stream_step(cfg: StreamConfig, state: StreamState,
     return new, metrics
 
 
+@_fp32_matmuls
+def chunk_stream_step(cfg: StreamConfig, state: StreamState,
+                      x_chunk: jnp.ndarray,
+                      masks: jnp.ndarray | None = None,
+                      round_valid: jnp.ndarray | None = None,
+                      ) -> tuple[StreamState, RoundMetrics]:
+    """K rounds for one network in ONE dispatch: fused covariance fold of
+    the whole (K, n, p) chunk (:func:`online_update_chunk` — one kernel
+    launch, one HBM band writeback), then ONE scheduler decision at the
+    chunk boundary, then one compression/detection pass over the chunk's
+    (K·n, p) epoch view.
+
+    The Table-1 bill stays per-EPOCH exact: the chunk books K per-round
+    drift records (and K flag-free compression/monitoring epochs) even
+    though only one decision is evaluated — the WSN would still aggregate
+    every epoch; only the eigenvector-phase *decisions* are amortized.
+
+    ``masks`` is the chunk's (K, p) per-round liveness schedule; any
+    liveness change across the chunk (vs. the state's last-seen liveness)
+    raises the scheduler's churn trigger at the boundary.  ``round_valid``
+    (K,) flags which rounds are real — 0 rounds (stream tail padding, or
+    an engine slot whose stream ends mid-chunk) contribute nothing to the
+    fold, the stages, the books, or the round counter.
+
+    At K=1 this is bit-identical to :func:`stream_step` (the chunk kernel
+    with weight 1 is the per-round kernel, and every booking term reduces
+    to the per-round expression exactly) — the differential guarantee
+    behind ``chunked_stream_run(..., probe_every=1)``.
+
+    With a compression and/or detection stage configured, the chunk body
+    takes the FUSED path by default (``cfg.fused``; DESIGN.md Sec. 14):
+    one mega-kernel (:func:`repro.kernels.ops.fused_stream_update`) loads
+    each chunk tile into VMEM once and emits the band delta AND the stage
+    outputs — 1 ``pallas_call`` per chunk body instead of 3.  The stages
+    are speculated against the pre-decision basis (bit-identical to the
+    post-decision basis whenever the scheduler does not fire — no refresh
+    path touches a state that did not fire); on the refresh rounds a
+    pure-jnp twin (:func:`repro.kernels.ops.fused_stream_stages_blocked`,
+    the kernel's stage arithmetic to fp32 rounding) recomputes them
+    against the rotated basis under ``lax.cond``.  At fp32 the fused path is bit-identical to
+    the split path; ``cfg.precision="bf16"`` halves the kernel's tile
+    traffic (fp32 accumulation) at tolerance-level divergence — note the
+    ε flag decision then happens in tile precision, so the fp32-measured
+    sink error can overshoot ε by the bf16 rounding (~1e-3 relative);
+    deployments that need the bound exact in fp32 keep the default
+    precision.  Quantized
+    compression (``score_bits > 0`` — the quantizer needs the whole
+    round's scores between projection and reconstruction) and (K, n, p)
+    per-reading dropout masks (their pairwise counts need a second kernel
+    pass anyway) keep the split path.
+
+    The fold (with the fused kernel), the stage twin and the books run
+    under the named scopes ``pca.fold``, ``pca.twin`` and ``pca.books``
+    (the scheduler adds ``pca.probe`` and ``pca.refresh``), which a device
+    trace carries in each op's ``op_name`` path.
+
+    The body is two halves around the scheduler's decision:
+    :func:`_chunk_fold` (the fold, the analytic half, churn) and
+    :func:`_chunk_finish` (the twin, the books, the metrics), joined here
+    by :meth:`~repro.streaming.scheduler.RecomputeScheduler.step`'s cond.
+    The engine's fleet step (:func:`engine_chunk_step_fn`) joins the same
+    halves with a fleet-level refresh of only the slots that fired.
+    """
+    folded = _chunk_fold(cfg, state, x_chunk, masks, round_valid)
+    sched, rho, fired = cfg.scheduler().step(
+        state.sched, folded.cov, _decision_round(state, x_chunk, round_valid),
+        churn=folded.churn)
+    return _chunk_finish(cfg, state, x_chunk, masks, round_valid, folded,
+                         sched, rho, fired)
+
+
 @functools.lru_cache(maxsize=None)
 def engine_chunk_step_fn(cfg: StreamConfig, *, masked: bool = False):
-    """The serving engine's jitted chunk body (DESIGN.md Sec. 17): the
-    vmapped :func:`chunk_stream_step` with the stacked fleet state DONATED.
+    """The serving engine's jitted chunk body (DESIGN.md Sec. 8.2, 17): the
+    fleet form of :func:`chunk_stream_step` with the stacked fleet state
+    DONATED.
+
+    The step is ``vmap`` of the pre-decision half (:func:`_chunk_fold`
+    and the scheduler's ``decide``), then the fleet-level
+    :meth:`~repro.streaming.scheduler.RecomputeScheduler.refresh_fired`
+    (only the slots that fired, in buckets), then ``vmap`` of the
+    post-decision half (:func:`_chunk_finish`).  A slot's result equals
+    the vmapped :func:`chunk_stream_step`'s, whose cond would lower to a
+    select that refreshes every slot, up to the rounding of batched
+    ``eigh``/``cholesky`` at another batch size.  The metrics carry
+    ``refresh_slots``, the slots the bucketed refresh computed.
 
     Memoized per (cfg, masked): every engine instance with the same config
     shares ONE jitted callable — and therefore one compilation cache —
@@ -506,13 +594,33 @@ def engine_chunk_step_fn(cfg: StreamConfig, *, masked: bool = False):
     so the engine and the ``engine.step*`` analysis contracts trace the
     exact same callable.
     """
+    sch = cfg.scheduler()
+
+    def pre(s, x, m, rv):
+        folded = _chunk_fold(cfg, s, x, m, rv)
+        rho, fired = sch.decide(s.sched, folded.cov,
+                                _decision_round(s, x, rv), folded.churn)
+        return folded, rho, fired
+
+    def post(s, x, m, rv, folded, sched, rho, fired):
+        return _chunk_finish(cfg, s, x, m, rv, folded, sch.book_round(sched),
+                             rho, fired)
+
+    @_fp32_matmuls
+    def fleet(states, x, masks, rv):
+        folded, rho, fired = jax.vmap(pre)(states, x, masks, rv)
+        sched, computed = sch.refresh_fired(states.sched, folded.cov, fired)
+        new, metrics = jax.vmap(post)(states, x, masks, rv, folded, sched,
+                                      rho, fired)
+        return new, metrics._replace(refresh_slots=computed)
+
     if masked:
         def body(s, x, m, rv):
-            return chunk_stream_step(cfg, s, x, m, rv)
+            return fleet(s, x, m, rv)
     else:
         def body(s, x, rv):
-            return chunk_stream_step(cfg, s, x, round_valid=rv)
-    return jax.jit(jax.vmap(body), donate_argnums=(0,))
+            return fleet(s, x, None, rv)
+    return jax.jit(body, donate_argnums=(0,))
 
 
 @functools.partial(jax.jit, static_argnums=0)

@@ -15,11 +15,19 @@ from the stale basis.  Each refresh books its paper-style communication cost
 through :func:`repro.core.costs.streaming_refresh_cost` so benchmarks can
 report accuracy-vs-communication exactly like Fig. 9/14.
 
-Everything is branch-free jittable: the refresh is a ``lax.cond`` whose
-batched (vmap) lowering evaluates both branches and selects per network —
-the cost model, not XLA, is the source of truth for what a WSN would pay.
-The drift probe and the refresh run under the named scopes ``pca.probe``
-and ``pca.refresh``, so a device trace gives each its own time.
+Everything is jittable.  :meth:`RecomputeScheduler.step` is one network's
+decision: :meth:`~RecomputeScheduler.decide` (the drift probe and the
+trigger), then a ``lax.cond`` on the trigger between
+:meth:`~RecomputeScheduler.refreshed` and the state as it was.  Every
+single-network and scan driver takes that path; under ``vmap`` (the batched
+and sharded fleet drivers) the cond lowers to a select that evaluates the
+refresh for every network and keeps it where the trigger fired — the cost
+model, not XLA, is the source of truth for what a WSN would pay.  The
+serving engine's fleet step instead decides for every slot and then runs
+:meth:`~RecomputeScheduler.refresh_fired`: the refresh of only the slots
+that fired, in fixed-size buckets (:func:`bucket_size`).  The drift probe
+and the refresh run under the named scopes ``pca.probe`` and
+``pca.refresh``, so a device trace gives each its own time.
 """
 
 from __future__ import annotations
@@ -36,7 +44,7 @@ from repro.streaming.online_cov import (OnlineCovariance, online_estimate,
                                         online_total_variance)
 
 __all__ = ["RecomputeScheduler", "SchedulerState", "retained_fraction",
-           "ortho_refresh", "ortho_refresh_evals"]
+           "ortho_refresh", "ortho_refresh_evals", "bucket_size"]
 
 
 def retained_fraction(band_est: jnp.ndarray, W: jnp.ndarray,
@@ -108,6 +116,17 @@ class SchedulerState(NamedTuple):
     #                           ones before the first — consumers clamp)
 
 
+def bucket_size(slots: int) -> int:
+    """Slots refreshed per pass of :meth:`RecomputeScheduler.refresh_fired`
+    in a fleet of ``slots``: a sixteenth of the fleet, at least 8, rounded
+    up to a multiple of 8 and at most the fleet.  A pass costs about what
+    B slots' refreshes cost, so a step pays ``ceil(fired / B)`` of them:
+    one or two at the usual fired share of about a tenth, S/B when every
+    slot fires.  On a v5e at 256 slots of the wsn-1m region shape a
+    sixteenth beat an eighth and a quarter (PERF.md Sec. 6)."""
+    return min(slots, -(-max(8, slots // 16) // 8) * 8)
+
+
 @dataclasses.dataclass(frozen=True)
 class RecomputeScheduler:
     """Policy + cost parameters (static; the state is the pytree above).
@@ -157,6 +176,56 @@ class RecomputeScheduler:
             p, self.q, self.n_max, self.c_max, self.refresh_iters,
             self.link_loss, self.max_retries).communication
 
+    def _probe(self, state: SchedulerState, cov_state: OnlineCovariance):
+        with jax.named_scope("pca.probe"):
+            band_est = online_estimate(cov_state)
+            total_var = online_total_variance(cov_state)
+            rho = retained_fraction(band_est, state.W, total_var)
+        return band_est, total_var, rho
+
+    def _trigger(self, state: SchedulerState, rho: jnp.ndarray,
+                 round_index: jnp.ndarray, churn) -> jnp.ndarray:
+        past_warmup = round_index >= self.warmup_rounds
+        never_fit = state.refreshes == 0
+        drifted = (state.rho_ref - rho) > self.drift_threshold
+        return past_warmup & (never_fit | drifted | jnp.asarray(churn))
+
+    def _refresh(self, state: SchedulerState, band_est: jnp.ndarray,
+                 total_var: jnp.ndarray) -> SchedulerState:
+        W_new, lam_new = ortho_refresh_evals(band_est, state.W,
+                                             self.refresh_iters)
+        rho_new = retained_fraction(band_est, W_new, total_var)
+        return SchedulerState(
+            W=W_new,
+            rho_ref=rho_new,
+            refreshes=state.refreshes + 1,
+            comm_packets=state.comm_packets
+            + self.refresh_cost(state.W.shape[0]),
+            lam=lam_new,
+        )
+
+    def decide(self, state: SchedulerState, cov_state: OnlineCovariance,
+               round_index: jnp.ndarray, churn: jnp.ndarray | bool = False,
+               ) -> tuple[jnp.ndarray, jnp.ndarray]:
+        """The drift probe and the trigger of :meth:`step`, without the
+        refresh: ``(rho, trigger)``."""
+        rho = self._probe(state, cov_state)[2]
+        return rho, self._trigger(state, rho, round_index, churn)
+
+    def refreshed(self, state: SchedulerState,
+                  cov_state: OnlineCovariance) -> SchedulerState:
+        """The state of one network after a refresh against its live
+        covariance: the new basis and λ̂, the retained fraction it measures,
+        one more refresh and its bill (no round cost: :meth:`book_round`)."""
+        with jax.named_scope("pca.refresh"):
+            return self._refresh(state, online_estimate(cov_state),
+                                 online_total_variance(cov_state))
+
+    def book_round(self, state: SchedulerState) -> SchedulerState:
+        """Book one round's drift record."""
+        return state._replace(
+            comm_packets=state.comm_packets + self.round_cost())
+
     def step(self, state: SchedulerState, cov_state: OnlineCovariance,
              round_index: jnp.ndarray,
              churn: jnp.ndarray | bool = False,
@@ -173,34 +242,59 @@ class RecomputeScheduler:
         trigger (after warmup) instead of waiting for the retained-variance
         estimate to catch up over the forgetting window.
         """
-        p = state.W.shape[0]
-        with jax.named_scope("pca.probe"):
-            band_est = online_estimate(cov_state)
-            total_var = online_total_variance(cov_state)
-            rho = retained_fraction(band_est, state.W, total_var)
-
-        past_warmup = round_index >= self.warmup_rounds
-        never_fit = state.refreshes == 0
-        drifted = (state.rho_ref - rho) > self.drift_threshold
-        trigger = past_warmup & (never_fit | drifted | jnp.asarray(churn))
+        band_est, total_var, rho = self._probe(state, cov_state)
+        trigger = self._trigger(state, rho, round_index, churn)
 
         @jax.named_scope("pca.refresh")
         def do_refresh(_):
-            W_new, lam_new = ortho_refresh_evals(band_est, state.W,
-                                                 self.refresh_iters)
-            rho_new = retained_fraction(band_est, W_new, total_var)
-            return SchedulerState(
-                W=W_new,
-                rho_ref=rho_new,
-                refreshes=state.refreshes + 1,
-                comm_packets=state.comm_packets + self.refresh_cost(p),
-                lam=lam_new,
-            )
+            return self._refresh(state, band_est, total_var)
 
         def keep(_):
             return state
 
         new_state = jax.lax.cond(trigger, do_refresh, keep, operand=None)
-        new_state = new_state._replace(
-            comm_packets=new_state.comm_packets + self.round_cost())
-        return new_state, rho, trigger
+        return self.book_round(new_state), rho, trigger
+
+    def refresh_fired(self, states: SchedulerState,
+                      cov_states: OnlineCovariance, fired: jnp.ndarray,
+                      ) -> tuple[SchedulerState, jnp.ndarray]:
+        """:meth:`refreshed` for the slots of a fleet whose trigger fired,
+        and for no other: the fleet-level refresh of the serving engine.
+
+        ``states``/``cov_states`` carry a leading slots axis S and ``fired``
+        is the (S,) trigger.  The slot indices are ordered fired first, and
+        a ``while_loop`` of ``ceil(n_fired / B)`` passes (B =
+        :func:`bucket_size` of S) gathers the next B of them, refreshes them
+        under ``vmap`` and scatters them back; entries past ``n_fired`` in
+        the last pass (slots that did not fire, or padding) are computed and
+        dropped (an out-of-range scatter index), so a slot that did not fire
+        keeps its state bit for bit.
+        The pass count never leaves the device.  Returns the new states and
+        the slots computed (passes × B, an int32 scalar).  No round cost is
+        booked (:meth:`book_round`).
+        """
+        S = fired.shape[0]
+        B = bucket_size(S)
+        passes_max = -(-S // B)
+        with jax.named_scope("pca.refresh"):
+            order = jnp.pad(jnp.argsort(jnp.logical_not(fired), stable=True),
+                            (0, passes_max * B - S))
+            n_fired = jnp.sum(fired, dtype=jnp.int32)
+            passes = (n_fired + (B - 1)) // B
+
+            def one_pass(carry):
+                i, out = carry
+                idx = jax.lax.dynamic_slice(order, (i * B,), (B,))
+                part = jax.vmap(self.refreshed)(
+                    jax.tree.map(lambda a: a[idx], states),
+                    jax.tree.map(lambda a: a[idx], cov_states))
+                dst = jnp.where(i * B + jnp.arange(B) < n_fired, idx, S)
+                out = jax.tree.map(
+                    lambda full, new: full.at[dst].set(new, mode="drop"),
+                    out, part)
+                return i + 1, out
+
+            _, new_states = jax.lax.while_loop(
+                lambda carry: carry[0] < passes, one_pass,
+                (jnp.zeros((), jnp.int32), states))
+        return new_states, passes * B

@@ -24,7 +24,8 @@ from repro.analysis import contracts
 from repro.analysis.jaxpr_lint import (CollectiveBudget, ForbidInLoops,
                                        Fp32Accumulators, NoF64,
                                        PrimitiveBudget, collective_counts,
-                                       count_primitive, count_primitives)
+                                       count_primitive, count_primitives,
+                                       iter_eqns)
 from repro.core import costs
 from repro.streaming.driver import (StreamConfig, chunk_stream_step,
                                     stream_init, stream_run)
@@ -118,6 +119,27 @@ class TestRegisteredContracts:
         assert results, f"{cid} produced no rule results"
         bad = [r.line() for r in results if not r.ok]
         assert not bad, "\n".join(bad)
+
+    def test_engine_step_refresh_is_bucketed(self):
+        """The engine step's refresh runs inside the bucket loop on B slots:
+        its ``eigh`` is batched over B = bucket_size(S), not over the S
+        slots a vmapped select would refresh."""
+        from repro.streaming.driver import engine_chunk_step_fn
+        from repro.streaming.scheduler import bucket_size
+        slots = 16
+        cfg = StreamConfig(p=8, q=2, halfwidth=1, warmup_rounds=2,
+                           interpret=True)
+        states = jax.vmap(lambda k: stream_init(cfg, k))(
+            jax.random.split(jax.random.PRNGKey(0), slots))
+        jx = jax.make_jaxpr(engine_chunk_step_fn(cfg))(
+            states, jnp.zeros((slots, 2, 4, 8), jnp.float32),
+            jnp.ones((slots, 2), jnp.float32))
+        sites = [site for site in iter_eqns(jx) if site.name == "eigh"]
+        assert len(sites) == 1
+        (site,) = sites
+        assert bucket_size(slots) == 8
+        assert site.eqn.invars[0].aval.shape == (8, 2, 2)
+        assert "while_body" in site.path
 
     def test_hierarchy_refresh_collective_budget(self):
         """Exactly one all_gather + two psums (trace partial and refresh

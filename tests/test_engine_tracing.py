@@ -78,15 +78,17 @@ def test_engine_programs_carry_their_scopes(fleet):
     admit = _op_names(_admit_splice_fn(SLOTS).lower(
         states, states, books, det_books, mask))
     assert any("pca.admit" in n for n in admit)
+    refresh_books = (jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
     retire = _op_names(_slot_summary_fn(cfg).lower(
-        states, books, det_books, np.int32(0)))
+        states, books, det_books, refresh_books, np.int32(0)))
     assert any("pca.retire" in n for n in retire)
     x = jnp.zeros((SLOTS, K, N, P), jnp.float32)
     rv = jnp.ones((SLOTS, K), jnp.float32)
     _, metrics = engine_chunk_step_fn(cfg)(
         jax.tree.map(jnp.copy, states), x, rv)
     acc = _op_names(_books_fn(True, True, 1.5).lower(
-        books, det_books, metrics.compression, metrics.detection,
+        books, det_books, refresh_books, metrics.compression,
+        metrics.detection, metrics.did_refresh, metrics.refresh_slots,
         np.ones(SLOTS, np.float32)))
     assert any("pca.books" in n for n in acc)
 
