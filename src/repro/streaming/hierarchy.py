@@ -174,7 +174,10 @@ def hierarchical_stream_run(cfg: StreamConfig, mesh, states: StreamState,
     over the trace partials and the refresh-boundary flags, which JAX
     binds as one psum per operand (contract ``hierarchy.refresh`` pins
     this budget at the jaxpr level — see the registration at the bottom of
-    this module).
+    this module).  The merge (the energy records, the collectives and the
+    top-``q_fleet`` selection) runs under the named scope ``pca.merge``,
+    which a device trace carries in each op's ``op_name`` path beside the
+    chunk body's ``pca.*`` scopes.
 
     Returns ``(final_states, metrics, fleet)`` where states/metrics are the
     per-region leaves of the flat driver (regions-leading) and ``fleet``
@@ -206,19 +209,20 @@ def hierarchical_stream_run(cfg: StreamConfig, mesh, states: StreamState,
         fin, metrics = batched_stream_run(cfg, states_l, xs_l, masks_l,
                                           chunk=chunk,
                                           probe_every=probe_every)
-        # level-2 records: per-region energies + trace partials
-        lam_l, den_l = jax.vmap(region_energies)(fin)
-        lam_table = jax.lax.all_gather(lam_l, axis, tiled=True)
-        # one psum call over the trace partials and the per-boundary
-        # refresh flags (JAX binds it leaf by leaf: two psum equations);
-        # the flags' fleet-wide sum books one merge per decision boundary
-        # at which ANY region refreshed, plus the final merge when none
-        # fired
-        total_var, fired = jax.lax.psum(
-            (jnp.sum(den_l),
-             jnp.sum(metrics.did_refresh.astype(jnp.float32), axis=0)),
-            axis)
-        basis = merge_fleet(lam_table, total_var, qf)
+        with jax.named_scope("pca.merge"):
+            # level-2 records: per-region energies + trace partials
+            lam_l, den_l = jax.vmap(region_energies)(fin)
+            lam_table = jax.lax.all_gather(lam_l, axis, tiled=True)
+            # one psum call over the trace partials and the per-boundary
+            # refresh flags (JAX binds it leaf by leaf: two psum
+            # equations); the flags' fleet-wide sum books one merge per
+            # decision boundary at which ANY region refreshed, plus the
+            # final merge when none fired
+            total_var, fired = jax.lax.psum(
+                (jnp.sum(den_l),
+                 jnp.sum(metrics.did_refresh.astype(jnp.float32), axis=0)),
+                axis)
+            basis = merge_fleet(lam_table, total_var, qf)
         merges = jnp.maximum(jnp.sum(fired > 0), 1).astype(jnp.int32)
         fleet = FleetMerge(basis=basis, merge_epochs=merges,
                            merge_packets=merges * jnp.asarray(merge_price,
