@@ -12,15 +12,20 @@ Layering (DESIGN.md Sec. 8.3):
   the fused multi-round cov-update kernel, one scheduler decision per
   ``probe_every`` rounds, per-epoch cost booking kept exact.
   ``probe_every=1`` is bit-identical to the per-round driver.
-* :func:`batched_stream_run` — ``jax.vmap`` of the run over a leading
-  networks axis: hundreds of independent sensor networks stream concurrently
-  in one program.  The scheduler's ``lax.cond`` lowers to a select, so each
-  round costs one (masked) refresh for the whole batch while the *booked*
-  WSN cost stays per-network exact.
-* :func:`engine_chunk_step_fn` — the serving engine's fleet step: the chunk
-  body split at the decision, with the refresh computed only for the slots
+* :func:`_fleet_chunk_step` — one chunk for a whole fleet: the chunk body
+  split at the decision, with the refresh computed only for the networks
   that fired (:meth:`repro.streaming.scheduler.RecomputeScheduler.
   refresh_fired`) instead of a select over the whole fleet.
+* :func:`batched_stream_run` — hundreds of independent sensor networks
+  stream concurrently in one program, over a leading networks axis.  With
+  ``chunk`` set it is a ``lax.scan`` of :func:`_fleet_chunk_step` over the
+  decision steps, so a decision refreshes only the networks that fired;
+  the per-round path (``chunk=None``) is ``jax.vmap`` of
+  :func:`stream_run`, whose ``lax.cond`` lowers to a select that costs one
+  (masked) refresh for the whole batch every round.  Either way the
+  *booked* WSN cost stays per-network exact.
+* :func:`engine_chunk_step_fn` — the serving engine's jitted step:
+  :func:`_fleet_chunk_step` with the fleet state donated.
 * :func:`sharded_stream_run` — the batched run inside ``shard_map`` with the
   networks axis split over the mesh data axis
   (:func:`repro.distributed.sharding.network_axis_spec`); per-network state
@@ -552,8 +557,9 @@ def chunk_stream_step(cfg: StreamConfig, state: StreamState,
     :func:`_chunk_fold` (the fold, the analytic half, churn) and
     :func:`_chunk_finish` (the twin, the books, the metrics), joined here
     by :meth:`~repro.streaming.scheduler.RecomputeScheduler.step`'s cond.
-    The engine's fleet step (:func:`engine_chunk_step_fn`) joins the same
-    halves with a fleet-level refresh of only the slots that fired.
+    The fleet body (:func:`_fleet_chunk_step`, which the engine's step and
+    the batch drivers' chunk path run) joins the same halves with a
+    fleet-level refresh of only the networks that fired.
     """
     folded = _chunk_fold(cfg, state, x_chunk, masks, round_valid)
     sched, rho, fired = cfg.scheduler().step(
@@ -563,21 +569,56 @@ def chunk_stream_step(cfg: StreamConfig, state: StreamState,
                          sched, rho, fired)
 
 
+@_fp32_matmuls
+def _fleet_chunk_step(cfg: StreamConfig, states: StreamState,
+                      x: jnp.ndarray, masks: jnp.ndarray | None,
+                      rv: jnp.ndarray | None,
+                      ) -> tuple[StreamState, RoundMetrics, jnp.ndarray]:
+    """One chunk for every network of a fleet: the fleet form of
+    :func:`chunk_stream_step`, shared by the engine's step
+    (:func:`engine_chunk_step_fn`) and the batch drivers' chunk scan
+    (:func:`batched_stream_run`).
+
+    ``states`` carries a leading networks axis S; ``x`` is (S, K, n, p),
+    ``masks`` (S, K, p) or None and ``rv`` (S, K) or None.  The body is
+    ``vmap`` of the pre-decision half (:func:`_chunk_fold` and the
+    scheduler's ``decide``), then the fleet-level
+    :meth:`~repro.streaming.scheduler.RecomputeScheduler.refresh_fired`
+    (only the networks that fired, in buckets of
+    :func:`~repro.streaming.scheduler.bucket_size` of S), then ``vmap`` of
+    the post-decision half (:func:`_chunk_finish`).  A network's result
+    equals the vmapped :func:`chunk_stream_step`'s, whose cond would lower
+    to a select that refreshes every network, up to the rounding of
+    batched ``eigh``/``cholesky`` at another batch size; a network that did
+    not fire keeps its scheduler state bit for bit.  Returns the new
+    states, the metrics and the networks the refresh computed (an int32
+    scalar).
+    """
+    sch = cfg.scheduler()
+
+    def pre(s, xc, m, r):
+        folded = _chunk_fold(cfg, s, xc, m, r)
+        rho, fired = sch.decide(s.sched, folded.cov,
+                                _decision_round(s, xc, r), folded.churn)
+        return folded, rho, fired
+
+    def post(s, xc, m, r, folded, sched, rho, fired):
+        return _chunk_finish(cfg, s, xc, m, r, folded, sch.book_round(sched),
+                             rho, fired)
+
+    folded, rho, fired = jax.vmap(pre)(states, x, masks, rv)
+    sched, computed = sch.refresh_fired(states.sched, folded.cov, fired)
+    new, metrics = jax.vmap(post)(states, x, masks, rv, folded, sched, rho,
+                                  fired)
+    return new, metrics, computed
+
+
 @functools.lru_cache(maxsize=None)
 def engine_chunk_step_fn(cfg: StreamConfig, *, masked: bool = False):
-    """The serving engine's jitted chunk body (DESIGN.md Sec. 8.2, 17): the
-    fleet form of :func:`chunk_stream_step` with the stacked fleet state
-    DONATED.
-
-    The step is ``vmap`` of the pre-decision half (:func:`_chunk_fold`
-    and the scheduler's ``decide``), then the fleet-level
-    :meth:`~repro.streaming.scheduler.RecomputeScheduler.refresh_fired`
-    (only the slots that fired, in buckets), then ``vmap`` of the
-    post-decision half (:func:`_chunk_finish`).  A slot's result equals
-    the vmapped :func:`chunk_stream_step`'s, whose cond would lower to a
-    select that refreshes every slot, up to the rounding of batched
-    ``eigh``/``cholesky`` at another batch size.  The metrics carry
-    ``refresh_slots``, the slots the bucketed refresh computed.
+    """The serving engine's jitted chunk body (DESIGN.md Sec. 8.2, 17):
+    :func:`_fleet_chunk_step` with the stacked fleet state DONATED.  The
+    metrics carry ``refresh_slots``, the slots the bucketed refresh
+    computed.
 
     Memoized per (cfg, masked): every engine instance with the same config
     shares ONE jitted callable — and therefore one compilation cache —
@@ -594,24 +635,8 @@ def engine_chunk_step_fn(cfg: StreamConfig, *, masked: bool = False):
     so the engine and the ``engine.step*`` analysis contracts trace the
     exact same callable.
     """
-    sch = cfg.scheduler()
-
-    def pre(s, x, m, rv):
-        folded = _chunk_fold(cfg, s, x, m, rv)
-        rho, fired = sch.decide(s.sched, folded.cov,
-                                _decision_round(s, x, rv), folded.churn)
-        return folded, rho, fired
-
-    def post(s, x, m, rv, folded, sched, rho, fired):
-        return _chunk_finish(cfg, s, x, m, rv, folded, sch.book_round(sched),
-                             rho, fired)
-
-    @_fp32_matmuls
     def fleet(states, x, masks, rv):
-        folded, rho, fired = jax.vmap(pre)(states, x, masks, rv)
-        sched, computed = sch.refresh_fired(states.sched, folded.cov, fired)
-        new, metrics = jax.vmap(post)(states, x, masks, rv, folded, sched,
-                                      rho, fired)
+        new, metrics, computed = _fleet_chunk_step(cfg, states, x, masks, rv)
         return new, metrics._replace(refresh_slots=computed)
 
     if masked:
@@ -642,6 +667,37 @@ def stream_run(cfg: StreamConfig, state: StreamState, xs: jnp.ndarray,
         return stream_step(cfg, carry, x_round, mask)
 
     return jax.lax.scan(step, state, (xs, masks))
+
+
+def _decision_steps(xs: jnp.ndarray, masks: jnp.ndarray | None,
+                    chunk: int, probe_every: int | None, *, axis: int):
+    """The chunk drivers' decision steps over the rounds axis ``axis`` of
+    ``xs`` (and of ``masks``): ``(xs, masks, rv, steps, S)``, with S rounds
+    per decision (``probe_every``, default ``chunk``) and ``steps`` =
+    ceil(rounds / S).  A stream whose length is not divisible by S comes
+    back padded with zero rounds, and ``rv`` is then the (steps, S) round
+    validity that keeps them out of the fold and the books; ``rv`` is None
+    where nothing was padded."""
+    R = xs.shape[axis]
+    S = chunk if probe_every is None else probe_every
+    if chunk < 1 or S < 1:
+        raise ValueError(f"chunk/probe_every must be >= 1, got "
+                         f"{chunk}/{probe_every}")
+    if chunk % S != 0:
+        raise ValueError(f"probe_every ({S}) must divide chunk ({chunk})")
+    steps = -(-R // S)
+    pad = steps * S - R
+    if not pad:
+        return xs, masks, None, steps, S
+
+    def padded(a):
+        widths = [(0, 0)] * a.ndim
+        widths[axis] = (0, pad)
+        return jnp.pad(a, widths)
+
+    rv = (jnp.arange(steps * S) < R).astype(jnp.float32).reshape(steps, S)
+    return (padded(xs), None if masks is None else padded(masks), rv,
+            steps, S)
 
 
 @functools.partial(jax.jit, static_argnums=0,
@@ -675,48 +731,14 @@ def chunked_stream_run(cfg: StreamConfig, state: StreamState,
     rounds that contribute nothing (the tail chunk folds and books only
     its real rounds).
     """
-    R = xs.shape[0]
-    step_rounds = chunk if probe_every is None else probe_every
-    if chunk < 1 or step_rounds < 1:
-        raise ValueError(f"chunk/probe_every must be >= 1, got "
-                         f"{chunk}/{probe_every}")
-    if chunk % step_rounds != 0:
-        raise ValueError(
-            f"probe_every ({step_rounds}) must divide chunk ({chunk})")
-    S = step_rounds
-    n_steps = -(-R // S)
-    pad = n_steps * S - R
-    if pad:
-        xs = jnp.concatenate(
-            [xs, jnp.zeros((pad,) + xs.shape[1:], xs.dtype)], axis=0)
-        if masks is not None:
-            masks = jnp.concatenate(
-                [masks, jnp.zeros((pad,) + masks.shape[1:], masks.dtype)],
-                axis=0)
-        rv = jnp.concatenate([jnp.ones((R,), jnp.float32),
-                              jnp.zeros((pad,), jnp.float32)])
-        rv = rv.reshape(n_steps, S)
-    xs_c = xs.reshape(n_steps, S, *xs.shape[1:])
+    xs, masks, rv, steps, S = _decision_steps(xs, masks, chunk, probe_every,
+                                              axis=0)
+    xs_c = xs.reshape(steps, S, *xs.shape[1:])
     masks_c = None if masks is None \
-        else masks.reshape(n_steps, S, *masks.shape[1:])
-    if not pad and masks is None:
-        def step(carry, xc):
-            return chunk_stream_step(cfg, carry, xc)
-        return jax.lax.scan(step, state, xs_c)
-    if not pad:
-        def step(carry, xm):
-            xc, mc = xm
-            return chunk_stream_step(cfg, carry, xc, mc)
-        return jax.lax.scan(step, state, (xs_c, masks_c))
-    if masks is None:
-        def step(carry, xm):
-            xc, rc = xm
-            return chunk_stream_step(cfg, carry, xc, round_valid=rc)
-        return jax.lax.scan(step, state, (xs_c, rv))
+        else masks.reshape(steps, S, *masks.shape[1:])
 
-    def step(carry, xm):
-        xc, mc, rc = xm
-        return chunk_stream_step(cfg, carry, xc, mc, rc)
+    def step(carry, xmr):
+        return chunk_stream_step(cfg, carry, *xmr)
 
     return jax.lax.scan(step, state, (xs_c, masks_c, rv))
 
@@ -736,17 +758,29 @@ def batched_stream_run(cfg: StreamConfig, states: StreamState,
                        chunk: int | None = None,
                        probe_every: int | None = None,
                        ) -> tuple[StreamState, RoundMetrics]:
-    """vmap the scan over a fleet: ``xs`` is (networks, rounds, n, p).
+    """Stream a fleet: ``xs`` is (networks, rounds, n, p).
 
     ``masks`` (networks, rounds, p), if given, is the per-network liveness
     schedule.  Metrics come back as (networks, rounds) leaves.
 
-    ``chunk``, if set, switches every network to the chunk-granular driver
-    (:func:`chunked_stream_run` under the same vmap): one fused cov launch
-    and ONE refresh select per chunk for the whole fleet — the per-round
-    path pays the ``lax.cond``→select refresh for every round of every
-    network — with metrics at decision granularity.  ``chunk=None`` is the
-    per-round path, unchanged (``probe_every`` requires it).
+    ``chunk=None`` is the per-round path: ``vmap`` of :func:`stream_run`,
+    under which the scheduler's ``lax.cond`` lowers to a select that
+    refreshes every network at every round (``probe_every`` requires
+    ``chunk``).
+
+    ``chunk``, if set, gives every network :func:`chunked_stream_run`'s
+    semantics (decisions every ``probe_every`` rounds, default ``chunk``;
+    a tail that does not fill a decision step padded with rounds that
+    count for nothing) through a ``lax.scan`` over the decision steps of
+    the fleet body :func:`_fleet_chunk_step`: one fused cov launch per
+    step for the whole fleet, and a refresh of only the networks whose
+    trigger fired (in buckets of
+    :func:`~repro.streaming.scheduler.bucket_size` of the fleet), where
+    a vmapped :func:`chunked_stream_run` would refresh every network.  Each
+    step slices its rounds out of ``xs`` in place (the data block keeps
+    its networks-leading layout).  Metrics come back networks-leading at
+    decision granularity, (networks, decisions); ``refresh_slots`` stays
+    None.
     """
     if chunk is None:
         if probe_every is not None:
@@ -756,12 +790,23 @@ def batched_stream_run(cfg: StreamConfig, states: StreamState,
             return jax.vmap(lambda s, x: stream_run(cfg, s, x))(states, xs)
         return jax.vmap(lambda s, x, m: stream_run(cfg, s, x, m))(
             states, xs, masks)
-    if masks is None:
-        return jax.vmap(lambda s, x: chunked_stream_run(
-            cfg, s, x, chunk=chunk, probe_every=probe_every))(states, xs)
-    return jax.vmap(lambda s, x, m: chunked_stream_run(
-        cfg, s, x, m, chunk=chunk, probe_every=probe_every))(
-        states, xs, masks)
+    xs, masks, rv, steps, S = _decision_steps(xs, masks, chunk, probe_every,
+                                              axis=1)
+    networks = xs.shape[0]
+
+    def rounds_of(a, i):
+        return None if a is None \
+            else jax.lax.dynamic_slice_in_dim(a, i * S, S, axis=1)
+
+    def step(carry, ir):
+        i, r = ir
+        rc = None if r is None else jnp.broadcast_to(r, (networks, S))
+        new, metrics, _ = _fleet_chunk_step(cfg, carry, rounds_of(xs, i),
+                                            rounds_of(masks, i), rc)
+        return new, metrics
+
+    fin, metrics = jax.lax.scan(step, states, (jnp.arange(steps), rv))
+    return fin, jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), metrics)
 
 
 def sharded_stream_run(cfg: StreamConfig, mesh, states: StreamState,

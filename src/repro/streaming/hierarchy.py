@@ -11,8 +11,10 @@ synchronization rounds — not FLOPs — bound distributed PCA at scale
   own online banded covariance and drift-triggered orthogonal-iteration
   refreshes through the existing chunked drivers
   (:func:`repro.streaming.driver.batched_stream_run` — one fused cov-update
-  kernel launch per chunk, PR 5; with compression/detection stages
-  configured the launch is the Sec.-14 mega-kernel, and
+  kernel launch per chunk for all of a shard's regions, and a refresh of
+  only the regions whose trigger fired, in buckets; with
+  compression/detection stages configured the launch is the Sec.-14
+  mega-kernel, and
   ``StreamConfig.fused`` / ``StreamConfig.precision`` thread through each
   region's chunk body unchanged — the hierarchy adds no split/fused logic
   of its own).  Under the banded/local-covariance

@@ -19,13 +19,15 @@ Everything is jittable.  :meth:`RecomputeScheduler.step` is one network's
 decision: :meth:`~RecomputeScheduler.decide` (the drift probe and the
 trigger), then a ``lax.cond`` on the trigger between
 :meth:`~RecomputeScheduler.refreshed` and the state as it was.  Every
-single-network and scan driver takes that path; under ``vmap`` (the batched
-and sharded fleet drivers) the cond lowers to a select that evaluates the
-refresh for every network and keeps it where the trigger fired — the cost
-model, not XLA, is the source of truth for what a WSN would pay.  The
-serving engine's fleet step instead decides for every slot and then runs
-:meth:`~RecomputeScheduler.refresh_fired`: the refresh of only the slots
-that fired, in fixed-size buckets (:func:`bucket_size`).  The drift probe
+single-network and scan driver takes that path.  A fleet's chunk step (the
+serving engine's, and the batched, sharded and hierarchical drivers' with
+``chunk`` set) instead decides for every network and then runs
+:meth:`~RecomputeScheduler.refresh_fired`: the refresh of only the
+networks that fired, in fixed-size buckets (:func:`bucket_size`).  Only the
+batched per-round path (``chunk=None``) still runs ``step`` under
+``vmap``, where the cond lowers to a select that evaluates the refresh for
+every network and keeps it where the trigger fired — the cost model, not
+XLA, is the source of truth for what a WSN would pay.  The drift probe
 and the refresh run under the named scopes ``pca.probe`` and
 ``pca.refresh``, so a device trace gives each its own time.
 """
@@ -259,7 +261,8 @@ class RecomputeScheduler:
                       cov_states: OnlineCovariance, fired: jnp.ndarray,
                       ) -> tuple[SchedulerState, jnp.ndarray]:
         """:meth:`refreshed` for the slots of a fleet whose trigger fired,
-        and for no other: the fleet-level refresh of the serving engine.
+        and for no other: the fleet-level refresh of every fleet chunk
+        step (the serving engine's and the batch drivers').
 
         ``states``/``cov_states`` carry a leading slots axis S and ``fired``
         is the (S,) trigger.  The slot indices are ordered fired first, and

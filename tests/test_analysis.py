@@ -141,6 +141,29 @@ class TestRegisteredContracts:
         assert site.eqn.invars[0].aval.shape == (8, 2, 2)
         assert "while_body" in site.path
 
+    def test_batched_chunk_refresh_is_bucketed(self):
+        """The batch drivers' chunk path refreshes inside the same bucket
+        loop, once per decision step of its scan: its ``eigh`` is batched
+        over B = bucket_size(S) in a ``while`` body, not over the S
+        networks a vmapped select would refresh."""
+        from repro.streaming.driver import batched_stream_run
+        from repro.streaming.scheduler import bucket_size
+        networks = 16
+        cfg = StreamConfig(p=8, q=2, halfwidth=1, warmup_rounds=2,
+                           interpret=True)
+        states = jax.vmap(lambda k: stream_init(cfg, k))(
+            jax.random.split(jax.random.PRNGKey(0), networks))
+        jx = jax.make_jaxpr(
+            lambda s, x: batched_stream_run(cfg, s, x, chunk=2))(
+            states, jnp.zeros((networks, 6, 4, 8), jnp.float32))
+        sites = [site for site in iter_eqns(jx) if site.name == "eigh"]
+        assert len(sites) == 1
+        (site,) = sites
+        assert bucket_size(networks) == 8
+        assert site.eqn.invars[0].aval.shape == (8, 2, 2)
+        assert "while_body" in site.path
+        assert site.path.index("scan") < site.path.index("while_body")
+
     def test_hierarchy_refresh_collective_budget(self):
         """Exactly one all_gather + two psums (trace partial and refresh
         flags: JAX binds a psum of a tuple leaf by leaf) on the 'region'

@@ -134,6 +134,19 @@ def test_refresh_fired_leaves_the_others_bit_for_bit(warm):
     assert int(computed) == 2 * B
 
 
+@pytest.mark.parametrize("n_fired", FIRED_COUNTS)
+def test_refresh_fired_computes_whole_buckets_of_the_fired(warm, n_fired):
+    """No slot fired: no pass and no slot computed; f fired: ceil(f/B)
+    passes of B slots."""
+    cfg, states, _, _ = warm
+    fired = _firing(n_fired)
+    new, computed = cfg.scheduler().refresh_fired(
+        states.sched, states.cov, jnp.asarray(fired))
+    assert int(computed) == -(-n_fired // B) * B
+    np.testing.assert_array_equal(np.asarray(new.refreshes),
+                                  fired.astype(np.int32))
+
+
 @pytest.mark.parametrize("slots, want", [(1, 1), (2, 2), (8, 8), (12, 8),
                                          (16, 8), (200, 16), (256, 16),
                                          (1000, 64), (1024, 64)])
