@@ -1,18 +1,18 @@
 """Streaming fleet benchmark: rounds/s, chunking, and the accuracy frontier.
 
-Times the jitted vmap+scan fleet driver at a few fleet sizes (the serving
-hot path), sweeps the drift threshold to chart the scheduler's
-communication-vs-retained-variance tradeoff — the streaming analogue of the
-paper's Fig. 9/14 load curves — and sweeps the chunk size K of the
-chunk-granular driver (DESIGN.md Sec. 12) against the per-round path.
-CSV derived column:
+Times the fleet driver (``batched_stream_run``: a scan of the fleet body,
+one decision per round by default) at a few fleet sizes, sweeps the drift
+threshold to chart the scheduler's communication-vs-retained-variance
+tradeoff — the streaming analogue of the paper's Fig. 9/14 load curves —
+and sweeps the chunk size K of the fleet body (DESIGN.md Sec. 12) against
+K=1.  CSV derived column:
 
-* ``stream/fleet{B}`` — network-rounds per second at fleet size B
+* ``stream/fleet{B}`` — network-rounds per second at fleet size B (K=1)
 * ``stream/threshold{t}`` — "retained@end|refreshes|packets" per network
-* ``stream/perround_fleet{B}`` — the chunk sweep's per-round baseline
-* ``stream/chunk{K}_fleet{B}`` — "rounds/s|speedup|launches/round|selects/round"
+* ``stream/chunk1_fleet{B}`` — the chunk sweep's K=1 baseline
+* ``stream/chunk{K}_fleet{B}`` — "rounds/s|speedup|launches/round|eigh/round"
   where launches/round counts the cov-update Pallas launches per streamed
-  round and selects/round the refresh cond→selects, both read off the
+  round and eigh/round the refresh's ``eigh`` sites, both read off the
   traced chunk body's jaxpr (1/K each — the structural amortization claim)
 * ``stream/{split_fp32,fused_fp32,fused_bf16}_fleet{B}`` — the mega-kernel
   sweep (DESIGN.md Sec. 14): same data and chunk size with compression AND
@@ -67,7 +67,7 @@ def _count_prims(jaxpr, names, acc=None):
 
 
 def _chunk_body_counts(cfg, chunk: int) -> tuple[float, float]:
-    """(cov launches, refresh selects) per ROUND of the chunk body."""
+    """(cov launches, refresh eigh sites) per ROUND of the chunk body."""
     from repro.streaming import stream_init as s_init
     from repro.streaming.driver import chunk_stream_step
 
@@ -80,12 +80,12 @@ def _chunk_body_counts(cfg, chunk: int) -> tuple[float, float]:
 
 
 def chunk_sweep(smoke: bool = False, chunks: tuple[int, ...] | None = None):
-    """Per-round vs chunk-granular fleet driver at a few chunk sizes.
+    """The fleet driver at K=1 and at a few chunk sizes K.
 
     Same data, same config: only the dispatch granularity changes.  The
-    derived column records rounds/s, the speedup over the per-round
-    baseline, and the structural cov-launch / refresh-select counts per
-    round (1/K — the per-chunk launch verified on the jaxpr).
+    derived column records rounds/s, the speedup over K=1, and the
+    structural cov-launch / refresh ``eigh`` counts per round (1/K — the
+    per-chunk launch verified on the jaxpr).
     """
     out = []
     chunks = chunks or ((2, 8) if smoke else (2, 4, 8, 16))
@@ -108,16 +108,16 @@ def chunk_sweep(smoke: bool = False, chunks: tuple[int, ...] | None = None):
     _run()                                           # compile outside timing
     _, us0 = timed(_run, repeat=repeat)
     rps0 = B * n_rounds / (us0 / 1e6)
-    out.append(row(f"stream/perround_fleet{B}", us0, f"{rps0:.0f} rounds/s"))
+    out.append(row(f"stream/chunk1_fleet{B}", us0, f"{rps0:.0f} rounds/s"))
     for k in chunks:
         _run(chunk=k)                                # compile outside timing
         _, us = timed(_run, chunk=k, repeat=repeat)
         rps = B * n_rounds / (us / 1e6)
-        launches, selects = _chunk_body_counts(cfg, k)
+        launches, eighs = _chunk_body_counts(cfg, k)
         out.append(row(
             f"stream/chunk{k}_fleet{B}", us,
-            f"{rps:.0f} rounds/s|{us0 / us:.2f}x vs per-round|"
-            f"{launches:.3f} launches/round|{selects:.3f} selects/round"))
+            f"{rps:.0f} rounds/s|{us0 / us:.2f}x vs K=1|"
+            f"{launches:.3f} launches/round|{eighs:.3f} eigh/round"))
     return out
 
 

@@ -7,7 +7,8 @@ and a recompute scheduler refreshes the principal-component basis only when
 retained variance drifts — booking the paper-style communication cost of
 every refresh (Table 1 / costs.py).
 
-The fleet is vmap-batched: all networks stream in ONE jitted program (the
+The fleet is batched: all networks stream in ONE jitted program, a scan of
+the fleet body that refreshes only the networks whose trigger fired (the
 "millions of users" serving shape; on a mesh the networks axis shards over
 the data axis, see repro.streaming.driver.sharded_stream_run).  Halfway
 through the stream, half of the fleet suffers a distribution shift — watch
@@ -81,7 +82,8 @@ def main() -> None:
 
     total_rounds = N_NETWORKS * N_ROUNDS
     print(f"streamed {total_rounds} network-rounds in {dt:.1f} s "
-          f"({total_rounds / dt:.0f} rounds/s, one jitted vmap+scan program)")
+          f"({total_rounds / dt:.0f} rounds/s, one jitted scan of the "
+          f"fleet body)")
 
     stable, shifted = slice(0, N_NETWORKS // 2), slice(N_NETWORKS // 2, None)
     print("\n-- scheduler activity ------------------------------------")

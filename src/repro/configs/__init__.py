@@ -2,8 +2,7 @@
 
 from __future__ import annotations
 
-from repro.configs.base import (ArchConfig, ShapeConfig, SHAPES,
-                                applicable_shapes)
+from repro.configs.base import ArchConfig
 
 from repro.configs.mamba2_2p7b import CONFIG as mamba2_2p7b
 from repro.configs.chameleon_34b import CONFIG as chameleon_34b
@@ -27,8 +26,7 @@ ARCHS: dict[str, ArchConfig] = {
 
 ASSIGNED = [n for n in ARCHS if n != "lm100m"]
 
-__all__ = ["ArchConfig", "ShapeConfig", "SHAPES", "ARCHS", "ASSIGNED",
-           "applicable_shapes"]
+__all__ = ["ArchConfig", "ARCHS", "ASSIGNED"]
 
 
 def get(name: str) -> ArchConfig:

@@ -1,10 +1,8 @@
-"""Architecture + run configuration.
+"""Architecture configuration of the LM stack.
 
 One :class:`ArchConfig` per assigned architecture lives in
 ``repro/configs/<id>.py``; reduced smoke variants are derived with
-:meth:`ArchConfig.smoke`.  Shape sets (train_4k / prefill_32k / decode_32k /
-long_500k) are defined here and gated per-family (``long_500k`` requires
-sub-quadratic attention — DESIGN.md Sec. 4).
+:meth:`ArchConfig.smoke`.
 """
 
 from __future__ import annotations
@@ -64,11 +62,6 @@ class ArchConfig:
     @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
-
-    @property
-    def supports_long_context(self) -> bool:
-        """long_500k eligibility: sub-quadratic sequence mixing."""
-        return self.family in ("ssm", "hybrid")
 
     def param_count(self) -> int:
         """Approximate parameter count (embeddings + blocks)."""
@@ -131,27 +124,3 @@ class ArchConfig:
             n_global_layers=min(self.n_global_layers, 1),
             dtype="float32",
         )
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: Literal["train", "prefill", "decode"]
-
-
-SHAPES: dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
-
-
-def applicable_shapes(cfg: ArchConfig) -> list[str]:
-    """The shape cells this architecture runs (DESIGN.md Sec. 4)."""
-    out = ["train_4k", "prefill_32k", "decode_32k"]
-    if cfg.supports_long_context:
-        out.append("long_500k")
-    return out

@@ -3,8 +3,8 @@
 1,048,576 virtual sensors (fleet telemetry channels) sharded over all chips,
 banded covariance with half-width 128 after bandwidth reduction
 (local covariance hypothesis), q=32 principal components, 256-epoch update
-batches.  Not an LM architecture: consumed by the dry-run via
-repro.core.production.
+batches.  Not an LM architecture: its region shape is the benchmark's
+wsn-1m deployment, streamed through repro.streaming.hierarchy.
 
 The fleet is two-level (DESIGN.md Sec. 13): ``n_regions`` regions of
 ``region_p`` sensors each stream independently and merge per refresh over

@@ -26,8 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 __all__ = [
     "PARAM_RULES", "ACT_RULES", "param_rules", "act_rules",
     "activation_sharding", "shard_activation", "logical_to_pspec",
-    "network_axis_spec", "shard_networks",
-    "region_axis_spec", "shard_regions",
+    "network_axis_spec", "shard_networks", "shard_regions",
 ]
 
 # -- parameter logical axes -------------------------------------------------
@@ -143,25 +142,11 @@ def shard_networks(mesh: Mesh, tree, axis: str = "data"):
     return jax.tree.map(lambda x: jax.device_put(x, sharding), tree)
 
 
-def region_axis_spec(mesh: Mesh, axis: str = "region") -> PartitionSpec:
-    """PartitionSpec sharding the leading *regions* axis of a two-level fleet.
-
-    The hierarchical decomposition (DESIGN.md Sec. 13) splits the
-    million-sensor fleet into regions, each streaming its own banded
-    covariance + basis (:func:`network_axis_spec` one level down); the
-    regions axis maps onto the cross-host ``region`` mesh axis, and the ONLY
-    traffic that crosses it is the per-refresh merge collective
-    (``all_gather`` of the (q+1)-element energy records + ``psum`` of the
-    trace partials — the fleet analogue of the paper's A/F tree ops).
-    """
-    if axis not in mesh.axis_names:
-        raise ValueError(f"mesh has no axis {axis!r}: {mesh.axis_names}")
-    return PartitionSpec(axis)
-
-
 def shard_regions(mesh: Mesh, tree, axis: str = "region"):
-    """Device_put a regions-leading pytree with the hierarchy sharding."""
-    sharding = NamedSharding(mesh, region_axis_spec(mesh, axis))
+    """Device_put a regions-leading pytree of a two-level fleet (DESIGN.md
+    Sec. 13) with its regions axis over the cross-host ``region`` mesh
+    axis."""
+    sharding = NamedSharding(mesh, network_axis_spec(mesh, axis))
     return jax.tree.map(lambda x: jax.device_put(x, sharding), tree)
 
 

@@ -1,1 +1,2 @@
-"""Launch: production mesh, dry-run, train/serve entry points, roofline."""
+"""Launch: mesh construction, TPU tiling targets, the compile cache and
+post-compile HLO analysis."""

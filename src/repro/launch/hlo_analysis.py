@@ -12,7 +12,7 @@ Wire-byte model (per device, ring algorithms — the XLA default on ICI):
     collective-permute  N
 
 Hardware constants: :data:`DEVICE_PEAKS`, keyed by ``Device.device_kind``;
-the dry-run and the static certifier price programs for the v5e
+the static certifier prices programs for the v5e
 (:data:`TARGET_KIND`).
 """
 
@@ -44,7 +44,7 @@ DEVICE_PEAKS = {
     "TPU v5 lite": DevicePeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
 }
 
-# the chip the dry-run and the static resource certifier describe
+# the chip the static resource certifier describes
 TARGET_KIND = "TPU v5 lite"
 
 

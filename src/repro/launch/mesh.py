@@ -1,8 +1,8 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 Kept as functions (never module-level constants) so importing this module
-does not touch jax device state — the dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
+does not touch jax device state — a multi-device test sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before any jax
 initialization and then calls these.
 """
 
@@ -11,8 +11,7 @@ from __future__ import annotations
 import jax
 from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_local_mesh", "make_fleet_mesh",
-           "mesh_axis_sizes"]
+__all__ = ["make_local_mesh", "make_fleet_mesh", "mesh_axis_sizes"]
 
 
 def _auto_mesh(shape: tuple, axes: tuple):
@@ -21,17 +20,6 @@ def _auto_mesh(shape: tuple, axes: tuple):
     type is ``Explicit``, which makes sharded results reject unannotated
     gathers)."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 = 256 chips per pod; 2x16x16 = 512 chips for two pods.
-
-    Axes: 'data' carries DP/FSDP, 'model' carries TP/EP; 'pod' (multi-pod)
-    carries the cross-pod data-parallel / FSDP dimension over DCI.
-    """
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):

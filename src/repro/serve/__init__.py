@@ -1,2 +1,2 @@
-"""Serving substrate: KV-cache LM engine + streaming-PCA fleet engine,
-both with continuous batching over a fixed device batch."""
+"""Serving substrate: the streaming-PCA fleet engine, with continuous
+batching over a fixed device batch."""

@@ -1,25 +1,19 @@
-"""Serving engines: LM decode + streaming-PCA fleets, continuous batching.
+"""Serving engine: streaming-PCA fleets with continuous batching.
 
-Two slot-based engines in the vLLM style share the pattern *fixed device
-batch, host-side slot management, jitted steps*:
-
-* :class:`Engine` — the LM path: **prefill** runs per-request and writes the
-  slot's region of the decode state; **decode** advances all active slots one
-  token per call; finished slots (EOS or max_tokens) are refilled from the
-  queue.
-* :class:`StreamingPCAEngine` — the sensor path (DESIGN.md Sec. 8.4/12/17):
-  each slot holds one live sensor network; every engine step stages the
-  next K-round chunk per slot and folds it through the jitted batched chunk
-  step (:func:`repro.streaming.driver.engine_chunk_step_fn` — the fleet
-  form of :func:`~repro.streaming.driver.chunk_stream_step` with the fleet
-  state donated so XLA updates it in place), drift-triggered basis
-  refreshes happen at chunk boundaries inside the step, computed only for
-  the slots whose trigger fired, and exhausted streams retire with their
-  final basis + Table-1 communication bill.
-  ``StreamConfig.fused``/``precision`` flow straight through the vmapped
-  step: with stages configured each slot's chunk body is the one-launch
-  mega-kernel (DESIGN.md Sec. 14), and ``precision="bf16"`` stages the
-  chunk tiles in bf16 while all engine-visible state stays fp32.
+:class:`StreamingPCAEngine` is slot based in the vLLM style — *fixed device
+batch, host-side slot management, jitted steps* (DESIGN.md Sec. 8.4/12/17):
+each slot holds one live sensor network; every engine step stages the
+next K-round chunk per slot and folds it through the jitted batched chunk
+step (:func:`repro.streaming.driver.engine_chunk_step_fn` — the fleet
+form of :func:`~repro.streaming.driver.chunk_stream_step` with the fleet
+state donated so XLA updates it in place), drift-triggered basis
+refreshes happen at chunk boundaries inside the step, computed only for
+the slots whose trigger fired, and exhausted streams retire with their
+final basis + Table-1 communication bill.
+``StreamConfig.fused``/``precision`` flow straight through the vmapped
+step: with stages configured each slot's chunk body is the one-launch
+mega-kernel (DESIGN.md Sec. 14), and ``precision="bf16"`` stages the
+chunk tiles in bf16 while all engine-visible state stays fp32.
 
 With ``pipeline=True`` the hot loop is fully pipelined (DESIGN.md Sec. 17):
 staging runs through two pinned, engine-owned host buffers whose uploads
@@ -43,7 +37,7 @@ optional JSONL sink.  The host phases are ``engine.*`` profiler spans and
 the engine's own device programs run under ``pca.admit``, ``pca.books``
 and ``pca.retire`` named scopes, so a trace puts names on both.
 
-The streaming engine is fault-aware (DESIGN.md Sec. 9): each slot carries a
+The engine is fault-aware (DESIGN.md Sec. 9): each slot carries a
 :class:`repro.runtime.health.HealthMonitor` driven by a *logical* clock (one
 tick per engine step, so verdicts are deterministic).  A slot whose network
 reports too few alive sensors stops heartbeating; once the monitor rules the
@@ -52,9 +46,6 @@ shows a later revival, a continuation request is re-queued from the revival
 round.  Whenever the live-network count changes, the engine re-plans its
 device mesh through :func:`repro.runtime.elastic.plan_mesh` (the WSN-fleet
 analogue of elastic rescale after host death).
-
-The decode state is the stacked pytree from repro.models.transformer; slot
-management is pure Python (host side), the steps are jitted.
 """
 
 from __future__ import annotations
@@ -68,7 +59,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models import transformer as T
 from repro.runtime.elastic import RescalePlan, plan_mesh
 from repro.runtime.health import HealthMonitor, StragglerPolicy
 from repro.serve.queue import AdmissionQueue, QueuePolicy
@@ -76,135 +66,10 @@ from repro.serve.telemetry import PhaseTimer, StepRecord, TelemetryRecorder
 from repro.streaming.driver import (StreamConfig, StreamState,
                                     engine_chunk_step_fn, stream_init)
 
-__all__ = ["Request", "ServeConfig", "Engine",
-           "StreamRequest", "StreamResult", "FleetSummary",
+__all__ = ["StreamRequest", "StreamResult", "FleetSummary",
            "StreamingPCAEngine"]
 
 
-@dataclasses.dataclass
-class Request:
-    prompt: np.ndarray               # (S,) int32
-    max_new_tokens: int = 32
-    eos_id: int = -1                 # -1: never stops early
-    # filled by the engine:
-    output: list[int] = dataclasses.field(default_factory=list)
-    done: bool = False
-
-
-@dataclasses.dataclass(frozen=True)
-class ServeConfig:
-    slots: int = 8
-    max_len: int = 512
-    dtype: str = "float32"
-
-
-class Engine:
-    def __init__(self, cfg, params, scfg: ServeConfig):
-        self.cfg = cfg
-        self.params = params
-        self.scfg = scfg
-        dt = jnp.dtype(scfg.dtype)
-        self.state = T.init_decode_state(cfg, scfg.slots, scfg.max_len,
-                                         dtype=dt)
-        self.pos = np.zeros(scfg.slots, np.int32)       # next content position
-        self.active: list[Request | None] = [None] * scfg.slots
-        self.queue: list[Request] = []
-
-        self._decode = jax.jit(
-            lambda p, tok, st, t: T.decode_step(p, cfg, tok, st, t))
-        self._prefill = jax.jit(
-            lambda p, tok, st, vl: T.prefill(p, cfg, tok, st, valid_len=vl))
-        self._last_tok = np.zeros((scfg.slots, 1), np.int32)
-
-    # -- request lifecycle ---------------------------------------------------
-    def submit(self, req: Request) -> None:
-        self.queue.append(req)
-
-    def _admit(self) -> None:
-        """Fill empty slots from the queue (continuous batching)."""
-        for slot in range(self.scfg.slots):
-            if self.active[slot] is None and self.queue:
-                req = self.queue.pop(0)
-                self._prefill_slot(slot, req)
-
-    def _bucket_len(self, s_len: int) -> int:
-        """Power-of-two prompt bucket: one compiled prefill per bucket
-        instead of one re-trace per distinct prompt length — compile count
-        O(log max_len).  Dense attention only: its caches are
-        position-indexed, so the pad suffix is masked out exactly (pos -1).
-        An SSM scan state would absorb the pad tokens, and MoE expert
-        routing counts them against expert capacity (pad top-1 slots can
-        evict real tokens' lower choices, shifting logits), so those
-        families keep exact lengths.
-        """
-        if self.cfg.family != "dense":
-            return s_len
-        bucket = 1 << (max(s_len, 8) - 1).bit_length()
-        return max(s_len, min(bucket, self.scfg.max_len))
-
-    def _prefill_slot(self, slot: int, req: Request) -> None:
-        """Run prefill for one request and splice its state into the slot.
-
-        Implementation note: prefill is batched over a single row, padded
-        to the power-of-two length bucket (masked via ``valid_len``); the
-        resulting caches are written into slot ``slot`` of the engine state.
-        """
-        s_len = len(req.prompt)
-        padded = np.zeros(self._bucket_len(s_len), np.int32)
-        padded[:s_len] = req.prompt
-        prompt = jnp.asarray(padded[None, :])
-        single = T.init_decode_state(self.cfg, 1, self.scfg.max_len,
-                                     dtype=jnp.dtype(self.scfg.dtype))
-        logits, single = self._prefill(self.params, prompt, single,
-                                       jnp.asarray(s_len, jnp.int32))
-
-        def splice(full, one):
-            # every stacked cache leaf has layout (L, B, ...): batch = axis 1
-            return full.at[:, slot:slot + 1].set(one)
-
-        self.state = jax.tree.map(splice, self.state, single)
-        tok = int(np.asarray(jnp.argmax(logits, -1))[0])
-        req.output.append(tok)
-        self._last_tok[slot, 0] = tok
-        self.pos[slot] = len(req.prompt)
-        self.active[slot] = req
-
-    # -- main loop ------------------------------------------------------------
-    def step(self) -> int:
-        """One decode step for all active slots; returns #active."""
-        self._admit()
-        live = [s for s in range(self.scfg.slots) if self.active[s]]
-        if not live:
-            return 0
-        # per-slot positions: unaligned requests decode together (the
-        # PosCache mask is derived from stored positions per row)
-        batch_tok = jnp.asarray(self._last_tok)
-        t_vec = jnp.asarray(self.pos, jnp.int32)
-        logits, self.state = self._decode(self.params, batch_tok, self.state,
-                                          t_vec)
-        next_tok = np.asarray(jnp.argmax(logits, -1))
-        for s in live:
-            req = self.active[s]
-            tok = int(next_tok[s])
-            req.output.append(tok)
-            self._last_tok[s, 0] = tok
-            self.pos[s] += 1
-            if (tok == req.eos_id
-                    or len(req.output) >= req.max_new_tokens
-                    or self.pos[s] >= self.scfg.max_len - 1):
-                req.done = True
-                self.active[s] = None
-        return len(live)
-
-    def run_until_done(self, max_steps: int = 10_000) -> None:
-        for _ in range(max_steps):
-            if self.step() == 0 and not self.queue:
-                return
-
-
-# ===========================================================================
-# Streaming-PCA fleet engine
-# ===========================================================================
 @dataclasses.dataclass(eq=False)       # identity equality: requests hold arrays
 class StreamRequest:
     """One live sensor network: a finite stream of measurement rounds.

@@ -18,8 +18,9 @@ compressor   ε-supervised compression stage (Sec. 2.4.1 on device): fused
 detector     T²/SPE event-detection stage (Sec. 2.4.3 on device): fused
              Pallas monitoring pass + Wilson-Hilferty thresholds with
              healthy-window recalibration after every basis refresh
-driver       single-network stream loop, ``jax.vmap`` batched multi-network
-             driver and the ``shard_map`` sharded runner
+driver       single-network stream loops (the reference), the fleet body
+             behind the batched multi-network driver and the engine's
+             step, and the ``shard_map`` sharded runner
 hierarchy    two-level million-sensor fleets (DESIGN.md Sec. 13): per-region
              streaming + cross-host energy-merge collectives over the
              ``region`` mesh axis, Table-1 merge billing

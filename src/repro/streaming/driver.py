@@ -1,4 +1,4 @@
-"""Stream drivers: single network, vmap-batched fleet, shard_map-sharded fleet.
+"""Stream drivers: single network, batched fleet, shard_map-sharded fleet.
 
 Layering (DESIGN.md Sec. 8.3):
 
@@ -12,27 +12,27 @@ Layering (DESIGN.md Sec. 8.3):
   the fused multi-round cov-update kernel, one scheduler decision per
   ``probe_every`` rounds, per-epoch cost booking kept exact.
   ``probe_every=1`` is bit-identical to the per-round driver.
+  These four are the per-network reference that the tests hold the fleet
+  body against.
 * :func:`_fleet_chunk_step` — one chunk for a whole fleet: the chunk body
   split at the decision, with the refresh computed only for the networks
   that fired (:meth:`repro.streaming.scheduler.RecomputeScheduler.
-  refresh_fired`) instead of a select over the whole fleet.
+  refresh_fired`) instead of a select over the whole fleet.  Every fleet
+  driver runs it.
 * :func:`batched_stream_run` — hundreds of independent sensor networks
-  stream concurrently in one program, over a leading networks axis.  With
-  ``chunk`` set it is a ``lax.scan`` of :func:`_fleet_chunk_step` over the
-  decision steps, so a decision refreshes only the networks that fired;
-  the per-round path (``chunk=None``) is ``jax.vmap`` of
-  :func:`stream_run`, whose ``lax.cond`` lowers to a select that costs one
-  (masked) refresh for the whole batch every round.  Either way the
-  *booked* WSN cost stays per-network exact.
+  stream concurrently in one program, over a leading networks axis: a
+  ``lax.scan`` of :func:`_fleet_chunk_step` over the decision steps
+  (``chunk`` rounds each, one by default), so a decision refreshes only
+  the networks that fired.  The *booked* WSN cost stays per-network exact.
 * :func:`engine_chunk_step_fn` — the serving engine's jitted step:
   :func:`_fleet_chunk_step` with the fleet state donated.
 * :func:`sharded_stream_run` — the batched run inside ``shard_map`` with the
-  networks axis split over the mesh data axis
+  networks axis split over a mesh axis
   (:func:`repro.distributed.sharding.network_axis_spec`); per-network state
   never crosses devices, so the fleet scales linearly with chips.
 * :func:`repro.streaming.hierarchy.hierarchical_stream_run` — the two-level
-  fleet form (DESIGN.md Sec. 13): the batched run per *region* shard over a
-  cross-host ``region`` mesh axis, topped by one ``all_gather``/``psum``
+  fleet form (DESIGN.md Sec. 13): the sharded run over a cross-host
+  ``region`` mesh axis (its level 1), topped by one ``all_gather``/``psum``
   merge of region bases per refresh — the million-sensor shape where every
   band fold stays a local problem and only (q+1)-element energy records
   ever cross hosts.
@@ -208,6 +208,9 @@ def stream_step(cfg: StreamConfig, state: StreamState, x_round: jnp.ndarray,
     topology churn — is reported to the scheduler as an unconditional drift
     trigger.  ``mask=None`` is the fault-free path, bit-identical to the
     pre-fault behavior.
+
+    A per-network reference: the fleet drivers run :func:`_fleet_chunk_step`,
+    which at K=1 gives the same results (tests/test_chunked_streaming.py).
     """
     if mask is None:
         cov = online_update(state.cov, x_round, forgetting=cfg.forgetting,
@@ -557,9 +560,10 @@ def chunk_stream_step(cfg: StreamConfig, state: StreamState,
     :func:`_chunk_fold` (the fold, the analytic half, churn) and
     :func:`_chunk_finish` (the twin, the books, the metrics), joined here
     by :meth:`~repro.streaming.scheduler.RecomputeScheduler.step`'s cond.
-    The fleet body (:func:`_fleet_chunk_step`, which the engine's step and
-    the batch drivers' chunk path run) joins the same halves with a
-    fleet-level refresh of only the networks that fired.
+    It is the per-network reference: the fleet body
+    (:func:`_fleet_chunk_step`, which the engine's step and every batch
+    driver run) joins the same halves with a fleet-level refresh of only
+    the networks that fired.
     """
     folded = _chunk_fold(cfg, state, x_chunk, masks, round_valid)
     sched, rho, fired = cfg.scheduler().step(
@@ -576,7 +580,7 @@ def _fleet_chunk_step(cfg: StreamConfig, states: StreamState,
                       ) -> tuple[StreamState, RoundMetrics, jnp.ndarray]:
     """One chunk for every network of a fleet: the fleet form of
     :func:`chunk_stream_step`, shared by the engine's step
-    (:func:`engine_chunk_step_fn`) and the batch drivers' chunk scan
+    (:func:`engine_chunk_step_fn`) and the batch drivers' scan
     (:func:`batched_stream_run`).
 
     ``states`` carries a leading networks axis S; ``x`` is (S, K, n, p),
@@ -656,6 +660,8 @@ def stream_run(cfg: StreamConfig, state: StreamState, xs: jnp.ndarray,
 
     ``masks`` (rounds, p), if given, carries the per-round sensor-liveness
     schedule (e.g. from :meth:`repro.core.faults.NodeChurn.liveness`).
+
+    The per-network reference of :func:`batched_stream_run` at ``chunk=1``.
     """
     if masks is None:
         def step(carry, x_round):
@@ -755,41 +761,29 @@ def batched_stream_init(cfg: StreamConfig, key: jax.Array, n_networks: int,
 def batched_stream_run(cfg: StreamConfig, states: StreamState,
                        xs: jnp.ndarray,
                        masks: jnp.ndarray | None = None, *,
-                       chunk: int | None = None,
+                       chunk: int = 1,
                        probe_every: int | None = None,
                        ) -> tuple[StreamState, RoundMetrics]:
     """Stream a fleet: ``xs`` is (networks, rounds, n, p).
 
     ``masks`` (networks, rounds, p), if given, is the per-network liveness
-    schedule.  Metrics come back as (networks, rounds) leaves.
+    schedule.
 
-    ``chunk=None`` is the per-round path: ``vmap`` of :func:`stream_run`,
-    under which the scheduler's ``lax.cond`` lowers to a select that
-    refreshes every network at every round (``probe_every`` requires
-    ``chunk``).
-
-    ``chunk``, if set, gives every network :func:`chunked_stream_run`'s
-    semantics (decisions every ``probe_every`` rounds, default ``chunk``;
-    a tail that does not fill a decision step padded with rounds that
-    count for nothing) through a ``lax.scan`` over the decision steps of
-    the fleet body :func:`_fleet_chunk_step`: one fused cov launch per
-    step for the whole fleet, and a refresh of only the networks whose
-    trigger fired (in buckets of
-    :func:`~repro.streaming.scheduler.bucket_size` of the fleet), where
-    a vmapped :func:`chunked_stream_run` would refresh every network.  Each
-    step slices its rounds out of ``xs`` in place (the data block keeps
-    its networks-leading layout).  Metrics come back networks-leading at
+    Every network gets :func:`chunked_stream_run`'s semantics (decisions
+    every ``probe_every`` rounds, default ``chunk``; a tail that does not
+    fill a decision step padded with rounds that count for nothing)
+    through a ``lax.scan`` over the decision steps of the fleet body
+    :func:`_fleet_chunk_step`: one fused cov launch per step for the whole
+    fleet, and a refresh of only the networks whose trigger fired (in
+    buckets of :func:`~repro.streaming.scheduler.bucket_size` of the
+    fleet), where a vmapped :func:`chunked_stream_run` would refresh every
+    network.  The default ``chunk=1`` decides once per round, the
+    semantics of :func:`stream_run` per network.  Each step slices its
+    rounds out of ``xs`` in place (the data block keeps its
+    networks-leading layout).  Metrics come back networks-leading at
     decision granularity, (networks, decisions); ``refresh_slots`` stays
     None.
     """
-    if chunk is None:
-        if probe_every is not None:
-            raise ValueError("probe_every requires chunk (the per-round "
-                             "path has no dispatch granularity to probe)")
-        if masks is None:
-            return jax.vmap(lambda s, x: stream_run(cfg, s, x))(states, xs)
-        return jax.vmap(lambda s, x, m: stream_run(cfg, s, x, m))(
-            states, xs, masks)
     xs, masks, rv, steps, S = _decision_steps(xs, masks, chunk, probe_every,
                                               axis=1)
     networks = xs.shape[0]
@@ -809,19 +803,19 @@ def batched_stream_run(cfg: StreamConfig, states: StreamState,
     return fin, jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), metrics)
 
 
-def sharded_stream_run(cfg: StreamConfig, mesh, states: StreamState,
-                       xs: jnp.ndarray, axis: str = "data", *,
-                       chunk: int | None = None,
-                       probe_every: int | None = None,
-                       ) -> tuple[StreamState, RoundMetrics]:
-    """The batched run with the networks axis sharded over ``axis``.
+def _sharded_fleet_run(cfg: StreamConfig, mesh, states: StreamState,
+                       xs: jnp.ndarray, masks: jnp.ndarray | None, *,
+                       axis: str, chunk: int, probe_every: int | None,
+                       merge=None, merge_specs=None):
+    """The level-1 ``shard_map`` of the sharded fleet drivers: each device
+    runs :func:`batched_stream_run` on its networks, with no collective
+    on per-network state (checked with ``check_vma=False`` because the
+    body is collective-free by construction).
 
-    Each device streams its local slice of the fleet; no collective touches
-    per-network state (checked with ``check_vma=False`` because the body is
-    collective-free by construction).  Requires the number of networks to be
-    divisible by the axis size.  ``chunk``/``probe_every`` thread through to
-    :func:`batched_stream_run` per shard (the chunked body is just as
-    collective-free).
+    ``merge``, if given, is called as ``merge(final_states, metrics)`` on
+    each shard after its run, inside the same ``shard_map`` (the
+    hierarchy's level 2), and its output, with out-specs ``merge_specs``,
+    is returned as a third result.
     """
     from repro.distributed.sharding import network_axis_spec
 
@@ -833,19 +827,43 @@ def sharded_stream_run(cfg: StreamConfig, mesh, states: StreamState,
             f"{n_networks} networks not divisible by axis {axis!r} "
             f"of size {axis_size}")
 
-    def local_run(states_l, xs_l):
-        return batched_stream_run(cfg, states_l, xs_l, chunk=chunk,
-                                  probe_every=probe_every)
+    def local_run(states_l, xs_l, masks_l=None):
+        fin, metrics = batched_stream_run(cfg, states_l, xs_l, masks_l,
+                                          chunk=chunk,
+                                          probe_every=probe_every)
+        if merge is None:
+            return fin, metrics
+        return fin, metrics, merge(fin, metrics)
 
-    fm = jax.shard_map(
-        local_run, mesh=mesh,
-        in_specs=(jax.tree.map(lambda _: spec, states),
-                  spec),
-        out_specs=(jax.tree.map(lambda _: spec, states),
-                   jax.tree.map(lambda _: spec, _metrics_template(cfg))),
-        check_vma=False,
-    )
-    return fm(states, xs)
+    state_specs = jax.tree.map(lambda _: spec, states)
+    out_specs = (state_specs,
+                 jax.tree.map(lambda _: spec, _metrics_template(cfg)))
+    if merge is not None:
+        out_specs += (merge_specs,)
+    args = (states, xs) if masks is None else (states, xs, masks)
+    fm = jax.shard_map(local_run, mesh=mesh,
+                       in_specs=(state_specs,) + (spec,) * (len(args) - 1),
+                       out_specs=out_specs, check_vma=False)
+    return fm(*args)
+
+
+def sharded_stream_run(cfg: StreamConfig, mesh, states: StreamState,
+                       xs: jnp.ndarray, masks: jnp.ndarray | None = None, *,
+                       axis: str = "data",
+                       chunk: int = 1,
+                       probe_every: int | None = None,
+                       ) -> tuple[StreamState, RoundMetrics]:
+    """The batched run with the networks axis sharded over ``axis``.
+
+    Each device streams its local slice of the fleet through
+    :func:`batched_stream_run` (``masks``, ``chunk`` and ``probe_every``
+    thread through per shard); no collective touches per-network state.
+    Requires the number of networks to be divisible by the axis size.
+    This is the level 1 of
+    :func:`repro.streaming.hierarchy.hierarchical_stream_run`.
+    """
+    return _sharded_fleet_run(cfg, mesh, states, xs, masks, axis=axis,
+                              chunk=chunk, probe_every=probe_every)
 
 
 # ===========================================================================

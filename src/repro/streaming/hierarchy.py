@@ -9,8 +9,9 @@ synchronization rounds — not FLOPs — bound distributed PCA at scale
 
 * **Level 1 (intra-region, no cross-host traffic):** every region streams its
   own online banded covariance and drift-triggered orthogonal-iteration
-  refreshes through the existing chunked drivers
-  (:func:`repro.streaming.driver.batched_stream_run` — one fused cov-update
+  refreshes through the sharded fleet driver
+  (:func:`repro.streaming.driver.sharded_stream_run`'s ``shard_map`` of
+  :func:`repro.streaming.driver.batched_stream_run` — one fused cov-update
   kernel launch per chunk for all of a shard's regions, and a refresh of
   only the regions whose trigger fired, in buckets; with
   compression/detection stages configured the launch is the Sec.-14
@@ -24,9 +25,8 @@ synchronization rounds — not FLOPs — bound distributed PCA at scale
   the block-diagonal embedding of per-region components, globally *selected*
   by subspace energy.  Each region contributes its (q_local + 1)-element
   record — the live Rayleigh energies ``diag(W^T C W)`` plus its trace
-  partial — via ``all_gather``/``psum`` over the ``region`` mesh axis
-  (:func:`repro.distributed.sharding.region_axis_spec`); the top
-  ``q_fleet`` components by energy form the fleet basis, and the fleet
+  partial — via ``all_gather``/``psum`` over the ``region`` mesh axis;
+  the top ``q_fleet`` components by energy form the fleet basis, and the fleet
   retained fraction is ``sum(selected energies) / psum(trace partials)``.
 
 The merge's packet bill is booked against the Table-1 accounting exactly
@@ -53,8 +53,7 @@ import jax.numpy as jnp
 from repro.core import costs
 from repro.core.covariance import banded_matmul_ref
 from repro.streaming.driver import (RoundMetrics, StreamConfig, StreamState,
-                                    _metrics_template, batched_stream_init,
-                                    batched_stream_run)
+                                    _sharded_fleet_run, batched_stream_init)
 from repro.streaming.online_cov import online_estimate, online_total_variance
 
 __all__ = ["FleetBasis", "FleetMerge", "region_energies", "merge_fleet",
@@ -161,17 +160,19 @@ def hierarchical_stream_run(cfg: StreamConfig, mesh, states: StreamState,
                             q_fleet: int | None = None,
                             c_regions: int | None = None,
                             axis: str = "region",
-                            chunk: int | None = None,
+                            chunk: int = 1,
                             probe_every: int | None = None,
                             ) -> tuple[StreamState, RoundMetrics, FleetMerge]:
     """Two-level run: per-region streaming + one cross-host fleet merge.
 
     ``xs`` is (regions, rounds, n, p_region); ``masks`` the optional
-    (regions, rounds, p_region) liveness schedule.  The regions axis is
-    sharded over mesh axis ``axis`` (:func:`region_axis_spec`); each shard
-    streams its local regions through :func:`batched_stream_run` (with the
-    PR 5 chunk/probe_every knobs threaded through) with NO cross-shard
-    traffic, then the merge runs as the run's only collectives: one tiled
+    (regions, rounds, p_region) liveness schedule.  Level 1 is
+    :func:`~repro.streaming.driver.sharded_stream_run` over mesh axis
+    ``axis``: each shard streams its local regions through
+    :func:`~repro.streaming.driver.batched_stream_run` (``chunk`` and
+    ``probe_every`` threaded through) with NO cross-shard traffic.  Then,
+    inside the same ``shard_map``, the merge runs as the run's only
+    collectives: one tiled
     ``all_gather`` of the (q_local,) energy records and one ``psum`` call
     over the trace partials and the refresh-boundary flags, which JAX
     binds as one psum per operand (contract ``hierarchy.refresh`` pins
@@ -191,26 +192,16 @@ def hierarchical_stream_run(cfg: StreamConfig, mesh, states: StreamState,
     """
     from jax.sharding import PartitionSpec
 
-    from repro.distributed.sharding import region_axis_spec
-
     n_regions = xs.shape[0]
     qf = cfg.q if q_fleet is None else q_fleet
     cr = cfg.c_max if c_regions is None else c_regions
     if qf > n_regions * cfg.q:
         raise ValueError(f"q_fleet={qf} > regions*q_local="
                          f"{n_regions * cfg.q}")
-    spec = region_axis_spec(mesh, axis)
-    axis_size = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
-    if n_regions % axis_size != 0:
-        raise ValueError(f"{n_regions} regions not divisible by axis "
-                         f"{axis!r} of size {axis_size}")
     merge_price = costs.lossy_merge_cost(
         cfg.q, cr, cfg.link_loss, cfg.max_retries).communication
 
-    def local_run(states_l, xs_l, masks_l=None):
-        fin, metrics = batched_stream_run(cfg, states_l, xs_l, masks_l,
-                                          chunk=chunk,
-                                          probe_every=probe_every)
+    def merge(fin, metrics):
         with jax.named_scope("pca.merge"):
             # level-2 records: per-region energies + trace partials
             lam_l, den_l = jax.vmap(region_energies)(fin)
@@ -226,28 +217,18 @@ def hierarchical_stream_run(cfg: StreamConfig, mesh, states: StreamState,
                 axis)
             basis = merge_fleet(lam_table, total_var, qf)
         merges = jnp.maximum(jnp.sum(fired > 0), 1).astype(jnp.int32)
-        fleet = FleetMerge(basis=basis, merge_epochs=merges,
-                           merge_packets=merges * jnp.asarray(merge_price,
-                                                              jnp.float32))
-        return fin, metrics, fleet
+        return FleetMerge(basis=basis, merge_epochs=merges,
+                          merge_packets=merges * jnp.asarray(merge_price,
+                                                             jnp.float32))
 
-    state_specs = jax.tree.map(lambda _: spec, states)
-    metric_specs = jax.tree.map(lambda _: spec, _metrics_template(cfg))
     rep = PartitionSpec()
     fleet_specs = FleetMerge(
         basis=FleetBasis(region=rep, col=rep, lam=rep, rho=rep,
                          lam_table=rep, total_variance=rep),
         merge_epochs=rep, merge_packets=rep)
-    out_specs = (state_specs, metric_specs, fleet_specs)
-    if masks is None:
-        fm = jax.shard_map(local_run, mesh=mesh,
-                           in_specs=(state_specs, spec),
-                           out_specs=out_specs, check_vma=False)
-        return fm(states, xs)
-    fm = jax.shard_map(local_run, mesh=mesh,
-                       in_specs=(state_specs, spec, spec),
-                       out_specs=out_specs, check_vma=False)
-    return fm(states, xs, masks)
+    return _sharded_fleet_run(cfg, mesh, states, xs, masks, axis=axis,
+                              chunk=chunk, probe_every=probe_every,
+                              merge=merge, merge_specs=fleet_specs)
 
 
 # ===========================================================================

@@ -18,18 +18,18 @@ report accuracy-vs-communication exactly like Fig. 9/14.
 Everything is jittable.  :meth:`RecomputeScheduler.step` is one network's
 decision: :meth:`~RecomputeScheduler.decide` (the drift probe and the
 trigger), then a ``lax.cond`` on the trigger between
-:meth:`~RecomputeScheduler.refreshed` and the state as it was.  Every
-single-network and scan driver takes that path.  A fleet's chunk step (the
-serving engine's, and the batched, sharded and hierarchical drivers' with
-``chunk`` set) instead decides for every network and then runs
-:meth:`~RecomputeScheduler.refresh_fired`: the refresh of only the
-networks that fired, in fixed-size buckets (:func:`bucket_size`).  Only the
-batched per-round path (``chunk=None``) still runs ``step`` under
-``vmap``, where the cond lowers to a select that evaluates the refresh for
-every network and keeps it where the trigger fired — the cost model, not
-XLA, is the source of truth for what a WSN would pay.  The drift probe
-and the refresh run under the named scopes ``pca.probe`` and
-``pca.refresh``, so a device trace gives each its own time.
+:meth:`~RecomputeScheduler.refreshed` and the state as it was.  Only the
+single-network drivers take that path; they are the reference the fleet
+is tested against.  Every fleet step (the serving engine's, and the
+batched, sharded and hierarchical drivers') instead decides for every
+network and then runs :meth:`~RecomputeScheduler.refresh_fired`: the
+refresh of only the networks that fired, in fixed-size buckets
+(:func:`bucket_size`).  Under ``vmap`` the cond would lower to a select
+that evaluates the refresh for every network, so no fleet driver vmaps
+``step``.  The cost model, not XLA, is the source of truth for what a WSN
+would pay.  The drift probe and the refresh run under the named scopes
+``pca.probe`` and ``pca.refresh``, so a device trace gives each its own
+time.
 """
 
 from __future__ import annotations
@@ -232,7 +232,9 @@ class RecomputeScheduler:
              round_index: jnp.ndarray,
              churn: jnp.ndarray | bool = False,
              ) -> tuple[SchedulerState, jnp.ndarray, jnp.ndarray]:
-        """One scheduling decision against the live covariance.
+        """One scheduling decision against the live covariance, for the
+        single-network drivers (the fleet steps run :meth:`decide` and
+        :meth:`refresh_fired`).
 
         Returns ``(new_state, rho, did_refresh)`` where ``rho`` is the
         retained fraction of the basis in effect *before* any refresh (the

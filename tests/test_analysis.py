@@ -164,6 +164,39 @@ class TestRegisteredContracts:
         assert "while_body" in site.path
         assert site.path.index("scan") < site.path.index("while_body")
 
+    @pytest.mark.parametrize("driver", ["batched", "hierarchical"])
+    def test_default_fleet_refresh_is_bucketed(self, driver):
+        """Called without ``chunk``, the batched and two-level drivers
+        decide every round through the same bucket loop: one ``eigh``,
+        batched over B = bucket_size(S) in a ``while`` body inside the
+        scan over rounds, not over the S networks a vmapped
+        ``stream_run`` would refresh with a select."""
+        from repro.launch.mesh import make_fleet_mesh
+        from repro.streaming.driver import batched_stream_run
+        from repro.streaming.hierarchy import hierarchical_stream_run
+        from repro.streaming.scheduler import bucket_size
+        networks = 16
+        cfg = StreamConfig(p=8, q=2, halfwidth=1, warmup_rounds=2,
+                           interpret=True)
+        states = jax.vmap(lambda k: stream_init(cfg, k))(
+            jax.random.split(jax.random.PRNGKey(0), networks))
+        mesh = make_fleet_mesh(region=1, data=1)
+
+        def run(s, x):
+            if driver == "batched":
+                return batched_stream_run(cfg, s, x)
+            return hierarchical_stream_run(cfg, mesh, s, x)
+
+        jx = jax.make_jaxpr(run)(
+            states, jnp.zeros((networks, 6, 4, 8), jnp.float32))
+        sites = [site for site in iter_eqns(jx) if site.name == "eigh"]
+        assert len(sites) == 1
+        (site,) = sites
+        assert bucket_size(networks) == 8
+        assert site.eqn.invars[0].aval.shape == (8, 2, 2)
+        assert "while_body" in site.path
+        assert site.path.index("scan") < site.path.index("while_body")
+
     def test_hierarchy_refresh_collective_budget(self):
         """Exactly one all_gather + two psums (trace partial and refresh
         flags: JAX binds a psum of a tuple leaf by leaf) on the 'region'

@@ -1,5 +1,6 @@
 """Application-layer behaviour: supervised compression (+/- eps guarantee),
-low-variance event detection, and the production-scale PIM steps."""
+low-variance event detection, and the refresh, deflation and transform
+steps on a banded problem."""
 
 import numpy as np
 import jax
@@ -7,11 +8,12 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import covariance as cov
-from repro.core import production as prod
-from repro.core.compression import SupervisedCompressor
+from repro.core.compression import SupervisedCompressor, scores
 from repro.core.events import LowVarianceDetector
 from repro.core.pca import DistributedPCA
+from repro.core.power_iteration import deflated_power_iteration
 from repro.sensors.dataset import berkeley_surrogate, kfold_blocks
+from repro.streaming.scheduler import ortho_refresh_evals
 
 
 @pytest.fixture(scope="module")
@@ -81,7 +83,8 @@ class TestEventDetection:
 
 
 class TestProductionSteps:
-    """The pod-scale step functions, validated on a small banded problem."""
+    """The refresh, deflation and transform steps of the served path,
+    validated on a small banded problem."""
 
     def _banded_problem(self, p=256, h=8, seed=0):
         rng = np.random.default_rng(seed)
@@ -93,12 +96,13 @@ class TestProductionSteps:
         return band, c
 
     def test_pim_block_step_converges(self):
+        """The scheduler's refresh (blocked orthogonal iteration with the
+        Cholesky ``inv(L)^T`` orthonormalization) converges to the top-4
+        eigenvalues."""
         band, c = self._banded_problem()
-        evals, evecs = np.linalg.eigh(c)
+        evals = np.linalg.eigvalsh(c)
         v = jax.random.normal(jax.random.PRNGKey(0), (256, 4), jnp.float32)
-        v, _ = prod.pim_block_step(band, v)
-        for _ in range(100):
-            v, rayleigh = prod.pim_block_step(band, v)
+        _, rayleigh = ortho_refresh_evals(band, v, 101)
         got = np.sort(np.asarray(rayleigh))[::-1]
         want = evals[::-1][:4]
         np.testing.assert_allclose(got, want, rtol=2e-2)
@@ -106,26 +110,24 @@ class TestProductionSteps:
     def test_pim_block_orthonormal(self):
         band, _ = self._banded_problem()
         v = jax.random.normal(jax.random.PRNGKey(1), (256, 4), jnp.float32)
-        v, _ = prod.pim_block_step(band, v)
+        v, _ = ortho_refresh_evals(band, v, 1)
         np.testing.assert_allclose(np.asarray(v.T @ v), np.eye(4), atol=1e-4)
 
     def test_pim_deflated_step_matches_matvec(self):
+        """Algorithm 2 over the banded matvec finds the top eigenvalue."""
         band, c = self._banded_problem()
-        v = jax.random.normal(jax.random.PRNGKey(2), (256,), jnp.float32)
-        v = v / jnp.linalg.norm(v)
-        w_prev = jnp.zeros((256, 3), jnp.float32)
-        for _ in range(200):
-            v, lam = prod.pim_deflated_step(band, v, w_prev)
+        res = deflated_power_iteration(
+            lambda v: cov.banded_matvec_ref(band, v), 256, 1,
+            jax.random.PRNGKey(2), t_max=200, delta=0.0)
         evals = np.linalg.eigvalsh(c)
-        assert abs(float(lam) - evals[-1]) < 1e-2 * evals[-1]
+        lam = float(res.eigenvalues[0])
+        assert abs(lam - evals[-1]) < 1e-2 * evals[-1]
 
     def test_transform_step_centered_scores(self):
-        band, _ = self._banded_problem()
         rng = np.random.default_rng(3)
-        w = jnp.asarray(np.linalg.qr(rng.normal(size=(256, 4)))[0],
-                        jnp.float32)
-        mean = jnp.asarray(rng.normal(size=256), jnp.float32)
-        x = jnp.asarray(rng.normal(size=(16, 256)), jnp.float32)
-        z = prod.transform_step(w, mean, x)
-        expected = (np.asarray(x) - np.asarray(mean)) @ np.asarray(w)
-        np.testing.assert_allclose(np.asarray(z), expected, atol=1e-4)
+        w = np.linalg.qr(rng.normal(size=(256, 4)))[0].astype(np.float32)
+        mean = rng.normal(size=256).astype(np.float32)
+        x = rng.normal(size=(16, 256)).astype(np.float32)
+        z = scores(w, x, mean)
+        expected = (x - mean) @ w
+        np.testing.assert_allclose(z, expected, atol=1e-4)

@@ -132,6 +132,24 @@ def test_probe_every_keeps_its_decision_cadence():
     _assert_trees_equal(got, want)
 
 
+def _sharded_masked_equals_batched(mesh, chunk):
+    """``sharded_stream_run`` with per-network liveness masks against
+    ``batched_stream_run`` with the same masks: bit for bit, and the churn
+    fires exactly the networks that lost a sensor."""
+    cfg, states, xs, masks, fired = _case(5, True, 8)
+    want = batched_stream_run(cfg, states, xs, masks, chunk=chunk)
+    np.testing.assert_array_equal(
+        np.asarray(want[1].did_refresh).any(axis=1), fired)
+    got = sharded_stream_run(cfg, mesh, states, xs, masks, chunk=chunk)
+    _assert_trees_equal(got, want)
+
+
+@pytest.mark.parametrize("chunk", [1, K])
+def test_sharded_run_takes_liveness_masks(chunk):
+    _sharded_masked_equals_batched(
+        jax.make_mesh((1, 1), ("data", "model")), chunk)
+
+
 # ---------------------------------------------------------------------------
 # The sharded and two-level drivers on 4 forced host devices
 # ---------------------------------------------------------------------------
@@ -166,6 +184,13 @@ def _drifting_fleet():
         0.0, 1.0, size=(S, 1, 1)).astype(np.float32)
     states = batched_stream_init(cfg, jax.random.PRNGKey(3), S)
     return cfg, states, jnp.asarray(xs)
+
+
+@needs_worker
+def test_mh_sharded_run_takes_liveness_masks():
+    assert jax.device_count() >= 4
+    _sharded_masked_equals_batched(jax.make_mesh((4, 1), ("data", "model")),
+                                   1)
 
 
 @needs_worker

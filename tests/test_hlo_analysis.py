@@ -135,35 +135,6 @@ class TestRoofline:
         assert terms.dominant == "compute"
         assert terms.bound_s == terms.compute_s
 
-    def test_analytic_cell_models(self):
-        from repro.launch.analytic import cell_model
-        # train flops ~ 6ND for a dense model
-        m = cell_model("llama3.2-1b", "train_4k", 256, microbatches=2)
-        from repro import configs
-        n = configs.get("llama3.2-1b").param_count()
-        d = 256 * 4096
-        assert m.flops_global == pytest.approx(6 * n * d, rel=0.25)
-        # decode flops = 2NB + attention over the 32k cache (dominant here)
-        md = cell_model("llama3.2-1b", "decode_32k", 256)
-        attn = 16 * 4 * 128 * 32768 * 32 * 64
-        assert md.flops_global == pytest.approx(2 * n * 128 + attn, rel=0.1)
-        # wsn transform: 2npq
-        mw = cell_model("wsn-1m", "transform", 256)
-        assert mw.flops_global == pytest.approx(2 * 256 * 1_048_576 * 32)
-
-    def test_dryrun_cell_enumeration(self):
-        from repro.launch.dryrun import all_cells, skipped_cells
-        cells = all_cells()
-        skips = skipped_cells()
-        # 40 assigned cells = run cells (LM) + documented skips
-        lm_cells = [c for c in cells if c[0] != "wsn-1m"]
-        assert len(lm_cells) + len(skips) == 40
-        # cov / pim_block / pim_deflated / transform / hier_merge
-        assert len([c for c in cells if c[0] == "wsn-1m"]) == 5
-        for arch, shape, why in skips:
-            assert shape == "long_500k"
-            assert "sub-quadratic" in why
-
 
 class TestDevicePeaks:
     def test_peaks_keyed_by_device_kind(self):

@@ -1,5 +1,5 @@
 """Training substrate: optimizer, data pipeline, checkpoint/resume (bitwise),
-fault-injection restart, elastic planning, health monitoring, serving."""
+fault-injection restart, elastic planning, health monitoring."""
 
 import os
 
@@ -231,90 +231,3 @@ class TestRuntime:
         # odd device counts still yield a plan
         plan2 = plan_mesh(7, prefer_model=16, global_batch=256)
         assert plan2.n_devices == 7
-
-
-class TestServing:
-    def test_engine_continuous_batching(self):
-        from repro.serve.engine import Engine, Request, ServeConfig
-        from repro.models import transformer as T
-        cfg = SMOKE
-        params = T.init_params(cfg, jax.random.PRNGKey(0))
-        eng = Engine(cfg, params, ServeConfig(slots=2, max_len=32))
-        rng = np.random.default_rng(0)
-        reqs = [Request(prompt=rng.integers(0, cfg.vocab_size, 4)
-                        .astype(np.int32), max_new_tokens=4)
-                for _ in range(5)]
-        for r in reqs:
-            eng.submit(r)
-        eng.run_until_done()
-        for r in reqs:
-            assert r.done
-            assert len(r.output) == 4
-            assert all(0 <= t < cfg.vocab_size for t in r.output)
-
-    def test_prefill_buckets_identical_first_token(self):
-        """Prompts are padded to power-of-two buckets (masked prefill):
-        the first token must be identical to the exact-length prefill for
-        every length, and distinct lengths inside one bucket must reuse
-        ONE compiled prefill (compile count O(log max_len))."""
-        from repro.serve.engine import Engine, Request, ServeConfig
-        from repro.models import transformer as T
-        cfg = SMOKE
-        params = T.init_params(cfg, jax.random.PRNGKey(0))
-        eng = Engine(cfg, params, ServeConfig(slots=1, max_len=32))
-        rng = np.random.default_rng(3)
-        lengths = [2, 3, 5, 7, 9, 12]
-        buckets = {eng._bucket_len(s) for s in lengths}
-        assert buckets == {8, 16}                    # not one trace per length
-        for s_len in lengths:
-            prompt = rng.integers(0, cfg.vocab_size, s_len).astype(np.int32)
-            req = Request(prompt=prompt, max_new_tokens=1)
-            eng.submit(req)
-            eng.run_until_done()
-            state = T.init_decode_state(cfg, 1, 32, dtype=jnp.float32)
-            logits, _ = T.prefill(params, cfg, jnp.asarray(prompt[None]),
-                                  state)
-            want = int(jnp.argmax(logits, -1)[0])
-            assert req.output[0] == want, s_len
-            np.testing.assert_allclose(
-                np.asarray(logits[0]),
-                np.asarray(self._bucketed_logits(eng, params, cfg, prompt)),
-                rtol=1e-5, atol=1e-5)
-
-    @staticmethod
-    def _bucketed_logits(eng, params, cfg, prompt):
-        """The engine's own bucketed prefill logits for a prompt."""
-        from repro.models import transformer as T
-        padded = np.zeros(eng._bucket_len(len(prompt)), np.int32)
-        padded[:len(prompt)] = prompt
-        state = T.init_decode_state(cfg, 1, eng.scfg.max_len,
-                                    dtype=jnp.float32)
-        logits, _ = T.prefill(params, cfg, jnp.asarray(padded[None]), state,
-                              valid_len=jnp.asarray(len(prompt), jnp.int32))
-        return logits[0]
-
-    def test_engine_matches_direct_decode(self):
-        """Engine output == direct prefill+decode for a single request."""
-        from repro.serve.engine import Engine, Request, ServeConfig
-        from repro.models import transformer as T
-        cfg = SMOKE
-        params = T.init_params(cfg, jax.random.PRNGKey(0))
-        prompt = np.arange(4, dtype=np.int32) + 7
-
-        eng = Engine(cfg, params, ServeConfig(slots=1, max_len=32))
-        req = Request(prompt=prompt, max_new_tokens=3)
-        eng.submit(req)
-        eng.run_until_done()
-
-        state = T.init_decode_state(cfg, 1, 32, dtype=jnp.float32)
-        logits, state = T.prefill(params, cfg, jnp.asarray(prompt[None]),
-                                  state)
-        toks = [int(jnp.argmax(logits, -1)[0])]
-        t = len(prompt)
-        for _ in range(2):
-            lg, state = T.decode_step(params, cfg,
-                                      jnp.asarray([[toks[-1]]], jnp.int32),
-                                      state, jnp.asarray(t, jnp.int32))
-            toks.append(int(jnp.argmax(lg, -1)[0]))
-            t += 1
-        assert req.output == toks
