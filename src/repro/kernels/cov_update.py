@@ -2,9 +2,10 @@
 
 ``delta[k, i] = sum_t x[t, i] * x[t, i + k - h]`` — the per-epoch sufficient
 statistic update of the paper's Sec. 3.3, batched over a measurement block.
-This is a rank-n update restricted to the band: per feature tile it is an
-elementwise product of the tile with a shifted view of the halo-padded batch,
-reduced over the batch axis (VPU work with an 8-deep sublane reduction).
+This is a rank-n update restricted to the band.  Per 128-column sub-tile
+of the feature block it is one MXU product of the sub-tile with its halo
+window, contracted over the row block, whose diagonals are the band rows
+(:func:`band_fold`).
 
 Tiling: grid = (feature blocks, batch blocks); the batch axis is the inner
 grid dimension so the output band tile is revisited consecutively and
@@ -15,12 +16,10 @@ Shifted reads (the Mosaic alignment rule): the batch is zero-padded by
 so every read of the halo slab starts on a lane-tile boundary.  Each grid
 step loads ONE ``(block_n, block_p + 2·halo_pad)`` window at the aligned
 offset ``i·block_p``; the centre tile is the window's columns from
-``halo_pad`` on, and the 2h+1 shifted views are lane rotations
-(``pltpu.roll``) of the window followed by a slice at lane 0 — no load at
-an unaligned lane offset, which the TPU compiler refuses.  The mask is
-applied once, to the window, so the centre tile and every shifted view
-carry it.  The band rows are produced eight at a time (one sublane tile)
-by a ``fori_loop``, so the kernel body does not grow with ``h``; the band
+``halo_pad`` on, and each sub-tile's window is the lane-aligned slice
+``[s·128, s·128 + 128 + 2·halo_pad)`` — no load at an unaligned lane
+offset, which the TPU compiler refuses.  The mask is applied once, to the
+window, so the centre tile and every window slice carry it.  The band
 output carries ``2h+1`` rounded up to a multiple of 8 rows, and the extra
 rows are sliced off.
 """
@@ -34,14 +33,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["LANES", "SUBLANES", "halo_pad", "band_rows_pad", "band_fold",
-           "load_window",
+__all__ = ["LANES", "SUBLANES", "FOLD_FORM", "halo_pad", "band_rows_pad",
+           "band_fold", "load_window",
            "cov_band_update_pallas", "cov_band_update_masked_pallas",
            "cov_band_update_chunk_pallas",
            "cov_band_update_chunk_masked_pallas"]
 
 LANES = 128                  # lane width of a TPU vreg (the last dim)
 SUBLANES = 8                 # fp32 sublanes of a vreg (the second-to-last)
+# how band_fold forms the band (an MXU product per sub-tile, skewed into
+# band rows); ops.kernel_block_plan reports it as ``fold_form``
+FOLD_FORM = "mxu"
 
 
 def halo_pad(halfwidth: int) -> int:
@@ -53,10 +55,14 @@ def halo_pad(halfwidth: int) -> int:
 
 def band_rows_pad(halfwidth: int) -> int:
     """Rows of the band accumulator: ``2h+1`` rounded up to a sublane
-    tile, and at least two tiles, so the fold is a loop for every ``h``
-    (the CPU interpreter inlines a one-trip loop into its neighbours, and
-    the fused code then rounds differently per kernel variant)."""
-    return max(2, -(-(2 * halfwidth + 1) // SUBLANES)) * SUBLANES
+    tile."""
+    return -(-(2 * halfwidth + 1) // SUBLANES) * SUBLANES
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, (dims, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def band_fold(xw: jnp.ndarray, win: jnp.ndarray, band_ref, *,
@@ -65,31 +71,56 @@ def band_fold(xw: jnp.ndarray, win: jnp.ndarray, band_ref, *,
 
     ``xw`` is the (block_n, block_p) centre tile (mask and row weight
     already applied) and ``win`` the fp32 (block_n, block_p + 2·pad)
-    masked halo window that starts ``pad`` columns left of the tile.  The
-    shifted view for diagonal k is ``win`` rotated left by ``k + pad - h``
-    lanes, then its first ``block_p`` columns (the rotation never wraps
-    into them: ``k + pad - h <= 2·pad``).  Rows past ``2h`` hold wrapped
-    columns and are sliced off by the callers.
+    masked halo window that starts ``pad`` columns left of the tile.
+    Rows past ``2h`` hold other diagonals and are sliced off by the
+    callers.
+
+    The band of one centre sub-tile of ``t`` columns (128, or the whole
+    tile when ``block_p`` is not a multiple of 128) is the diagonals of one
+    MXU product: ``P = xwᵀ · win_s`` over the row block, with ``win_s`` the
+    sub-tile's lane-aligned (block_n, t + 2·pad) window slice, holds
+    ``band[k, a] = P[a, a + k + pad − h]``.  Band row k is P's diagonal at
+    offset ``k + pad − h``, so row ``a`` of P has to move left by ``a``
+    (plus a constant) to line the diagonals up as columns.
+
+    Mosaic's strided ``pltpu.roll`` rotates row ``a`` by ``shift + a·stride``
+    lanes, and refuses both other ways to get a per-row shift of ``−a``: a
+    sublane roll strided along lanes ("stride dimension is the minor
+    most"), and a lane roll of stride ``W − 1`` (≡ −1; "the max shift in a
+    vreg … is larger than the vreg's width").  So the skew is a stride
+    +1 roll of the anti-diagonals: the centre columns are reversed first
+    (``xw · J``, J the t×t anti-identity; exact, its entries are 0/1), which
+    turns row ``a`` of P into row ``a′ = t−1−a``; a uniform left roll by
+    ``t − 1 + pad − h``, then the stride +1 roll (row ``a′`` right by
+    ``a′``), leaves column k holding band row k at column ``a′``.  The two
+    rolls stay apart: a strided roll's largest shift inside one vreg, its
+    shift mod 128 plus 127, must not pass the vreg's width.  The
+    transpose, cut to the band's rows, times J restores the column order.
+
+    Every dot runs at ``Precision.HIGHEST``: the fold is fp32, and the two
+    products with J are exact.  The MXU computes all ``t + 2·pad``
+    diagonals, ≈1.5× the band's 2h+1 at h = 128.
     """
     h = halfwidth
     pad = halo_pad(h)
-    width = win.shape[1]
     block_p = xw.shape[1]
     n_rows = band_ref.shape[0]
-    sub = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, block_p), 0)
-
-    def group(g, carry):
-        k0 = pl.multiple_of(g * SUBLANES, SUBLANES)
-        tile = jnp.zeros((SUBLANES, block_p), jnp.float32)
-        for r in range(SUBLANES):
-            shift = jax.lax.rem(width - (k0 + r + pad - h), width)
-            xs = pltpu.roll(win, shift, 1)[:, :block_p]
-            row = jnp.sum(xw * xs, axis=0, keepdims=True)   # (1, block_p)
-            tile = jnp.where(sub == r, row, tile)
-        band_ref[pl.ds(k0, SUBLANES), :] += tile.astype(band_ref.dtype)
-        return carry
-
-    jax.lax.fori_loop(0, n_rows // SUBLANES, group, 0)
+    t = LANES if block_p % LANES == 0 else block_p
+    width = t + 2 * pad
+    rr = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+    cc = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+    rev = jnp.where(rr + cc == t - 1, 1.0, 0.0).astype(jnp.float32)
+    lead = (width - (t - 1 + pad - h)) % width      # left by t−1+pad−h
+    for s in range(block_p // t):
+        lo = s * t
+        xr = _dot(xw[:, lo:lo + t], rev, ((1,), (0,)))      # (block_n, t)
+        prod = _dot(xr, win[:, lo:lo + width], ((0,), (0,)))  # (t, width)
+        # left by t−1+pad−h, then row a′ right by a′: column k holds band
+        # row k
+        prod = pltpu.roll(prod, lead, 1)
+        prod = pltpu.roll(prod, 0, 1, stride=1, stride_axis=0)
+        band = _dot(prod.T[:n_rows], rev, ((1,), (0,)))    # (n_rows, t)
+        band_ref[:, lo:lo + t] += band.astype(band_ref.dtype)
 
 
 def load_window(xpad_ref, mpad_ref, *, block_p: int, halfwidth: int

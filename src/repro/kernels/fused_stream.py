@@ -24,9 +24,11 @@ collapsing the chunk body from 3 kernel launches to 1.
 Tiling: the grid is (feature blocks, row blocks) — the cov chunk
 kernel's grid, with the same block specs and the same fold
 (:func:`repro.kernels.cov_update.band_fold`: one lane-aligned halo window
-per step, shifted views by lane rotation).  The band output block has a
-j-constant index map and is revisited consecutively across the row sweep
-of each feature block — the Pallas in-VMEM accumulation pattern.
+per step; the band of each 128-column sub-tile is the diagonals of one
+MXU product of the sub-tile with its window slice).  The band output
+block has a j-constant index map and is revisited consecutively across
+the row sweep of each feature block — the Pallas in-VMEM accumulation
+pattern.
 
 The stages run once per row block, on the FIRST feature step
 (``pl.program_id(0) == 0``), reading the full rows back out of the halo
@@ -42,13 +44,15 @@ buffers over the stage outputs on the chip).
 
 Precision: every tile is cast to fp32 on load and every accumulation runs
 in fp32 (``preferred_element_type=jnp.float32``), whatever the operand
-dtype — so the optional bf16 mode (the ops wrapper casts the large
-operands x/xpad/mask/W to bfloat16 before the call) halves the HBM tile
-traffic while the band fold and the stage reductions keep fp32
-accumulators.  With fp32 operands the band is the chunk kernel's fold and
-the stages are ``_supervised_kernel``/``_monitor_kernel``'s op order per
-row block; the compiled programs differ, so the split path agrees to fp32
-rounding, not bit for bit.
+dtype, and the fold's dots run at ``Precision.HIGHEST`` (a one-pass bf16
+MXU product would round the fp32 tiles) — so the optional bf16 mode (the
+ops wrapper casts the large operands x/xpad/mask/W to bfloat16 before the
+call) halves the HBM tile traffic while the band fold and the stage
+reductions keep fp32 accumulators.  With fp32 operands the band is the
+chunk kernel's fold and the stages are
+``_supervised_kernel``/``_monitor_kernel``'s op order per row block; the
+compiled programs differ, so the split path agrees to fp32 rounding, not
+bit for bit.
 """
 
 from __future__ import annotations

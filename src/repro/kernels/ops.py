@@ -13,7 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.banded_matvec import banded_matvec_pallas, banded_matmul_pallas
-from repro.kernels.cov_update import (cov_band_update_chunk_masked_pallas,
+from repro.kernels.cov_update import (FOLD_FORM,
+                                      cov_band_update_chunk_masked_pallas,
                                       cov_band_update_chunk_pallas,
                                       cov_band_update_pallas,
                                       cov_band_update_masked_pallas,
@@ -98,7 +99,9 @@ def kernel_block_plan(kind: str, *, rows: int | None = None,
     when both are, and ``halo_width`` (the full-width padded slab a banded
     kernel re-fetches per feature block) when ``halfwidth`` is given too.
     On a TPU the row target counts that slab
-    (:func:`repro.launch.tiling.block_targets`).
+    (:func:`repro.launch.tiling.block_targets`).  With ``halfwidth`` the
+    plan also names the form of the band fold the banded kernels run,
+    ``fold_form`` (:data:`repro.kernels.cov_update.FOLD_FORM`).
     """
     from repro.launch.tiling import block_targets
     t = block_targets(kind, dtype=dtype)
@@ -113,6 +116,8 @@ def kernel_block_plan(kind: str, *, rows: int | None = None,
         # the row target counts the blocks of one row at this width
         t = block_targets(kind, dtype=dtype, block_p=bp, p_pad=p_pad,
                           halo=halo)
+    if halfwidth is not None:
+        plan["fold_form"] = FOLD_FORM
     plan["row_target"] = t["rows"]
     if rows is not None:
         bn = _pick_block_padded(rows, t["rows"], t["row_align"])

@@ -12,16 +12,20 @@ TPU branch (v5e):
   128 and the row block a multiple of 8 (16), and the wrappers pad p and
   the row count up to them;
 * the feature target is 512 lanes at fp32 and double at bf16 (half the
-  bytes per lane): the band fold reads 8 bytes per feature for 2·(2h+1)
-  FLOPs, so it is bandwidth-bound and takes slabs as wide as the byte
-  budget allows;
+  bytes per lane).  The band fold is not bandwidth-bound at the wsn-1m
+  width (h = 128): the lane-roll fold it replaced ran at 1.1 % of its
+  roofline, bound by 2h+1 whole-window rotations on the XLU and the
+  multiply-adds on the VPU.  The MXU fold
+  (:func:`repro.kernels.cov_update.band_fold`) works per 128-column
+  sub-tile, so a wider feature block adds sub-tiles, not work per column;
 * the row target is the largest power of two whose double-buffered blocks
   fit half of :data:`VMEM_BYTES` (the other half holds the in-kernel
-  values: the halo window and its rotations, the stage temporaries and the
-  band accumulator).  The blocks of one row are counted per kernel family:
-  the x and mask tiles, the x and mask halo slabs — the whole padded row
-  plus both halos, re-read for every feature block — and the fp32 x_hat
-  and flag rows of the fused stages.
+  values: the halo window, one sub-tile's MXU product — (128, 128 + 2·pad)
+  fp32, 192 KiB at h = 128 — and its skewed copies, the stage temporaries
+  and the band accumulator).  The blocks of one row are counted per kernel
+  family: the x and mask tiles, the x and mask halo slabs — the whole
+  padded row plus both halos, re-read for every feature block — and the
+  fp32 x_hat and flag rows of the fused stages.
 
 The CPU backend runs every kernel in interpret mode (tests) and keeps the
 historical targets (rows 128, features 512) with no alignment, so

@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.ops import kernel_block_plan
 from repro.runtime.elastic import RescalePlan, plan_mesh
 from repro.runtime.health import HealthMonitor, StragglerPolicy
 from repro.serve.queue import AdmissionQueue, QueuePolicy
@@ -346,6 +347,9 @@ class StreamingPCAEngine:
             else AdmissionQueue(queue))
         self.telemetry: TelemetryRecorder | None = (
             TelemetryRecorder() if telemetry is True else telemetry or None)
+        if self.telemetry is not None:
+            self.telemetry.fold_form = kernel_block_plan(
+                "cov", p=cfg.p, halfwidth=cfg.halfwidth)["fold_form"]
         # region-aware slots (DESIGN.md Sec. 13): which region each slot is
         # streaming right now (-1 = idle), and the latest final result per
         # region — the merge inputs of fleet_summary()

@@ -18,9 +18,10 @@ values rather than causing its own transfer.
 
 ``summary()`` folds the ring into the serving headline numbers: p50/p99
 step latency, mean staged-vs-compute overlap fraction, prestage hit rate,
-throughput, admission/retirement totals, and the refresh's bucket fill:
+throughput, admission/retirement totals, the refresh's bucket fill:
 the slots whose trigger fired over the slots the bucketed refresh computed,
-as of the last retirement (the engine pulls its device counts there).
+as of the last retirement (the engine pulls its device counts there), and
+the band fold's form for the engine's configuration (``fold_form``).
 
 :class:`PhaseTimer` times the phases of one engine step (admit, stage,
 dispatch, bookkeeping, retirement) once each: every phase is a
@@ -163,6 +164,9 @@ class TelemetryRecorder:
         self.refresh_fired_slots = 0
         self.refresh_computed_slots = 0
         self._refresh_seen = (0, 0)
+        # the band fold's form for the engine's configuration
+        # (kernel_block_plan's fold_form), set once by the engine
+        self.fold_form: str | None = None
         self._sink: IO[str] | None = (
             open(jsonl_path, "a") if jsonl_path else None)
 
@@ -253,6 +257,7 @@ class TelemetryRecorder:
             "refresh_bucket_fill": (
                 self.refresh_fired_slots / self.refresh_computed_slots
                 if self.refresh_computed_slots else 0.0),
+            "fold_form": self.fold_form,
         }
 
     def reset(self) -> None:
